@@ -66,6 +66,7 @@ print(json.dumps({"r_cond": r, "local_frac": lf,
 def measure(steps: int = 8):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
+    env["JAX_PLATFORMS"] = "cpu"            # 8 host devices, never the chip
     out = subprocess.run([sys.executable, "-c", _MEASURE % {"steps": steps}],
                          capture_output=True, text=True, env=env,
                          cwd=str(ROOT), timeout=1800)

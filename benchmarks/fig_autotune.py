@@ -24,7 +24,6 @@ Emits CSV rows and ``artifacts/fig_autotune.json``.
 from __future__ import annotations
 
 import json
-import os
 import time
 import types
 
@@ -39,14 +38,7 @@ def _fake_mesh(data: int = 16, model: int = 16):
 
 
 def run(fast: bool = True) -> None:
-    # importing the dryrun launcher sets XLA_FLAGS for its own 512-device
-    # use; restore the harness environment (same dance as the tests)
-    saved = os.environ.get("XLA_FLAGS")
     from repro.launch.dryrun import comm_traffic_ledger
-    if saved is None:
-        os.environ.pop("XLA_FLAGS", None)
-    else:
-        os.environ["XLA_FLAGS"] = saved
     from repro.comm.topology import Topology
     from repro.config import SHAPES
     from repro.configs import get_config

@@ -13,6 +13,7 @@ import sys
 if os.environ.get("_EP_CHILD") != "1":
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"            # 8 host devices, never the chip
     env["_EP_CHILD"] = "1"
     env.setdefault("PYTHONPATH", "src")
     raise SystemExit(subprocess.call(

@@ -1,114 +1,39 @@
-"""jax version shims for the comm subsystem.
+"""Mesh and varying-manual-axes (vma) helpers for the comm subsystem.
 
-The repo targets the modern jax API (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``, ``jax.lax.axis_size``) but must also run on the
-0.4.x line shipped in some containers, where those spellings live under
-``jax.experimental`` or do not exist. Everything mesh/collective-shaped
-goes through this module so the rest of the codebase never version-checks.
+Collectives run inside ``jax.shard_map``, whose type system tracks which
+mesh axes a value varies over. The helpers here state the two casts the
+MoE layer needs, plus the one mesh spelling every launcher shares.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
-
-AxisName = Union[str, Tuple[str, ...]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` when available, else the experimental spelling.
-
-    The old implementation's replication checker predates the vma type
-    system and rejects valid programs our MoE layer emits (aux scalars
-    pmean'd over all axes), so it is disabled there; new jax applies its
-    own (sound) check.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """``jax.make_mesh`` with Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def axis_size(axis_name: Optional[AxisName]) -> int:
-    """Static size of a (possibly tuple) named axis inside shard_map."""
-    if axis_name is None:
-        return 1
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    # psum of a static python scalar folds to the axis size at trace time
-    return jax.lax.psum(1, axis_name)
-
-
-def axis_index(axis_name: AxisName):
-    """Combined (major-to-minor) index along one or several named axes."""
-    names = axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
-    idx = jnp.int32(0)
-    for a in names:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
-    return idx
-
-
-@jax.custom_vjp
-def optimization_barrier(xs):
-    """Differentiable ``jax.lax.optimization_barrier`` over a pytree.
-
-    0.4.x has no differentiation rule for the primitive, so the barrier
-    is wrapped in a custom_vjp with identity cotangents — sound because
-    the barrier only constrains *scheduling*, never values. Used by the
-    ``repro.sched`` pipeline to pin collective issue order inside the
-    differentiated train step.
-    """
-    return jax.lax.optimization_barrier(xs)
-
-
-def _ob_fwd(xs):
-    return optimization_barrier(xs), None
-
-
-def _ob_bwd(_, ct):
-    return (ct,)
-
-
-optimization_barrier.defvjp(_ob_fwd, _ob_bwd)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """A mesh with Auto axis types: GSPMD shards everything outside the
+    explicit ``shard_map`` regions (``jax.make_mesh`` defaults to
+    Explicit). ``devices`` defaults to ``jax.devices()``; a described
+    topology's devices give an ahead-of-time compile target."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def pvary_all(v, axes: Tuple[str, ...]):
-    """Mark ``v`` varying over ``axes`` (new-jax vma types) — the value
-    is unchanged. Used when a replicated-within-a-row value (e.g. the
-    all-gathered plan-reuse signature) is returned through out_specs
-    that treat it as per-device varying; old jax needs nothing."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None and hasattr(jax.lax, "pcast"):
-        vma = getattr(typeof(v), "vma", frozenset())
-        missing = tuple(a for a in axes if a not in vma)
-        if missing:
-            v = jax.lax.pcast(v, missing, to="varying")
-    return v
+    """Mark ``v`` varying over every axis in ``axes`` (value unchanged).
+
+    Used where a value that is replicated by construction (e.g. the
+    all-gathered plan-reuse signature) leaves through out_specs that
+    treat it as per-device varying, and to give both arms of a
+    ``lax.cond`` the same type."""
+    missing = tuple(a for a in axes if a not in jax.typeof(v).vma)
+    return jax.lax.pcast(v, missing, to="varying") if missing else v
 
 
 def pmean_all(v, axes: Tuple[str, ...]):
-    """pmean over all mesh axes regardless of the value's varying state.
-
-    New jax tracks varying-manual-axes (vma) types: a value replicated
-    over some axes must be pcast to varying before a pmean that names
-    them. Old jax has no vma concept and the plain pmean is correct.
-    """
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None and hasattr(jax.lax, "pcast"):
-        vma = getattr(typeof(v), "vma", frozenset())
-        missing = tuple(a for a in axes if a not in vma)
-        if missing:
-            v = jax.lax.pcast(v, missing, to="varying")
-    return jax.lax.pmean(v, axes)
+    """pmean over all ``axes`` whatever the value's varying state: a
+    value replicated over some of them must be cast to varying before a
+    pmean that names them."""
+    return jax.lax.pmean(pvary_all(v, axes), axes)

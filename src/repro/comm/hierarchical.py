@@ -26,7 +26,6 @@ from typing import NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.comm import compat
 from repro.comm.topology import Topology
 
 AxisName = Union[str, Tuple[str, ...]]
@@ -43,8 +42,8 @@ def hier_all_to_all(x, node_axis: str, local_axis: str):
     keyed on the destination node — same-column devices talk, one
     aggregated message per node pair.
     """
-    N = compat.axis_size(node_axis)
-    L = compat.axis_size(local_axis)
+    N = jax.lax.axis_size(node_axis)
+    L = jax.lax.axis_size(local_axis)
     M = N * L
     assert x.shape[0] % M == 0, (x.shape, N, L)
     chunk = x.shape[0] // M
@@ -124,12 +123,12 @@ class CommContext(NamedTuple):
     def size(self) -> int:
         if self.mode == "local":
             return 1
-        return compat.axis_size(self.axes)
+        return jax.lax.axis_size(self.axes)
 
     def index(self):
         if self.mode == "local":
             return 0
-        return compat.axis_index(self.axes)
+        return jax.lax.axis_index(self.axes)
 
     @property
     def node_axis(self) -> str:
@@ -142,23 +141,35 @@ class CommContext(NamedTuple):
         return self.axes[1]
 
     # -- collectives ---------------------------------------------------------
+    def _fenced(self, exchange, x):
+        """Run ``exchange`` between optimization barriers, so that flat
+        and hier hand the surrounding compute the same opaque array.
+        Unfenced, the TPU compiler folds hier's (node, local) reshape
+        into neighbouring reductions and sums in another order: the
+        values match flat's to the last ulp only in the forward pass,
+        and training drifts apart from the first update."""
+        x = jax.lax.optimization_barrier(x)
+        return jax.lax.optimization_barrier(exchange(x))
+
     def all_to_all(self, x):
         """Dispatch-layout exchange: dim 0 = one chunk per device."""
         if self.mode == "local":
             return x
         if self.mode == "hier":
-            return hier_all_to_all(x, self.node_axis, self.local_axis)
-        return jax.lax.all_to_all(x, self.axis_name, split_axis=0,
-                                  concat_axis=0, tiled=True)
+            return self._fenced(lambda b: hier_all_to_all(
+                b, self.node_axis, self.local_axis), x)
+        return self._fenced(lambda b: jax.lax.all_to_all(
+            b, self.axis_name, split_axis=0, concat_axis=0, tiled=True), x)
 
     def combine(self, x):
         """Combine-layout exchange (same chunk convention)."""
         if self.mode == "local":
             return x
         if self.mode == "hier":
-            return hier_combine(x, self.node_axis, self.local_axis)
-        return jax.lax.all_to_all(x, self.axis_name, split_axis=0,
-                                  concat_axis=0, tiled=True)
+            return self._fenced(lambda b: hier_combine(
+                b, self.node_axis, self.local_axis), x)
+        return self._fenced(lambda b: jax.lax.all_to_all(
+            b, self.axis_name, split_axis=0, concat_axis=0, tiled=True), x)
 
     # -- single-phase collectives (the dedup wire, repro.condense.wire) ------
     def node_all_to_all(self, x):

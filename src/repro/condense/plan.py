@@ -37,6 +37,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.comm.compat import pvary_all
 from repro.condense import backends as sim_backends
 
 Array = jnp.ndarray
@@ -277,17 +278,25 @@ def build_condense_plan(x, primary_expert, threshold, *, group_size: int,
         match = have & fresh & jnp.all(carry.expert == e0)
 
     group_base = (jnp.arange(T, dtype=jnp.int32) // G) * G
+    # inside shard_map both arms must return the same varying-axes type:
+    # every output derives from these inputs, so cast all of them to the
+    # union of the inputs' varying axes (a no-op outside shard_map)
+    vma = tuple(frozenset().union(*(
+        jax.typeof(a).vma for a in jax.tree.leaves((x, e0, sp3, carry)))))
+
+    def _typed(outs):
+        return tuple(pvary_all(o, vma) for o in outs)
 
     def _reuse(_):
         rep_idx = group_base + carry.rep
         is_rep = rep_idx == jnp.arange(T, dtype=jnp.int32)
         rate = 1.0 - jnp.mean(is_rep.astype(jnp.float32))
-        return (rep_idx, is_rep, sp3, rate, jnp.float32(0.0))
+        return _typed((rep_idx, is_rep, sp3, rate, jnp.float32(0.0)))
 
     def _build(_):
         out = _full_build()
-        return (out.rep_idx, out.is_rep, out.sim, out.rate,
-                out.measured_pairs)
+        return _typed((out.rep_idx, out.is_rep, out.sim, out.rate,
+                       out.measured_pairs))
 
     rep_idx, is_rep, sims, rate, pairs = jax.lax.cond(
         match, _reuse, _build, 0)

@@ -62,7 +62,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.comm import CommContext, compat
+from repro.comm import CommContext
 from repro.comm import dtypes as wdt
 from repro.sched import ChunkPlan, run_pipeline
 
@@ -181,8 +181,8 @@ def dedup_dispatch(xf, expert_idx, gate_w, valid, pos, *,
     and scatter-add-onto-zeros produce the same values and the codec
     formula is shared).
     """
-    N = compat.axis_size(comm.node_axis)
-    L = compat.axis_size(comm.local_axis)
+    N = jax.lax.axis_size(comm.node_axis)
+    L = jax.lax.axis_size(comm.local_axis)
     M = N * L
     T, k = expert_idx.shape
     d = xf.shape[1]

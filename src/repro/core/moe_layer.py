@@ -38,7 +38,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.comm import CommContext, compat
+from repro.comm import CommContext
 from repro.config import LuffyConfig, MoEConfig, ModelConfig
 from repro.core.gating import dispatch_positions, gate_apply, gate_init
 # The exchange decision/execution machinery lives in repro.plan
@@ -140,9 +140,9 @@ def moe_decode_allreduce(params, x, cfg: ModelConfig, *, capacity: int,
     overlap (``LuffyConfig.exec_mode="decode_overlap"``, DESIGN.md §13):
     issue that combine psum CONCURRENTLY with the shared-expert FFN —
     the two are data-independent (the shared FFN reads the pre-expert
-    hidden), so ``optimization_barrier`` pins the shared FFN between
-    psum issue and psum consumption and XLA's async collectives hide
-    the wire time behind the matmuls. The value graph is unchanged
+    hidden), so ``optimization_barrier`` ties the psum's consumption to
+    the shared FFN and XLA's async collectives hide the wire time behind
+    the matmuls. The value graph is unchanged
     (same operands, same addition order), so overlap is bit-identical
     to sync; with no shared experts or no mesh it degrades to sync.
     Returns (y, aux)."""
@@ -153,9 +153,9 @@ def moe_decode_allreduce(params, x, cfg: ModelConfig, *, capacity: int,
     n_seq, S, d = x.shape
     T = n_seq * S
     E = m.num_experts
-    M = 1 if axis_name is None else compat.axis_size(axis_name)
+    M = 1 if axis_name is None else jax.lax.axis_size(axis_name)
     E_local = E // M
-    my = 0 if axis_name is None else compat.axis_index(axis_name)
+    my = 0 if axis_name is None else jax.lax.axis_index(axis_name)
     C = capacity
 
     xf = x.reshape(T, d)
@@ -185,15 +185,15 @@ def moe_decode_allreduce(params, x, cfg: ModelConfig, *, capacity: int,
     sh = None
     if overlap and axis_name is not None and "shared" in params:
         from repro.models.blocks import ffn_apply
-        # barrier 1: the shared FFN may not be hoisted before the local
-        # expert partials exist; barrier 2: the psum may not be awaited
-        # before the shared FFN is done — together they bracket the
-        # shared-expert matmuls inside the collective's in-flight window
-        delta, x_b = compat.optimization_barrier((delta, x))
+        # the psum may not be awaited before the shared FFN is done. Its
+        # input is not tied to the expert partials: a barrier joins the
+        # varying axes of its operands, so the model-replicated shared
+        # output would be typed model-varying and the replicated out_spec
+        # of the caller would no longer hold
         sh = ffn_apply(params["shared"], cfg,
-                       _rms(x_b, params["norm"]["scale"]).astype(cdt))
+                       _rms(x, params["norm"]["scale"]).astype(cdt))
         delta = jax.lax.psum(delta, axis_name)
-        delta, sh = compat.optimization_barrier((delta, sh))
+        delta, sh = jax.lax.optimization_barrier((delta, sh))
     elif axis_name is not None:
         delta = jax.lax.psum(delta, axis_name)
     y = (xf + delta.astype(xf.dtype)).reshape(n_seq, S, d)

@@ -11,9 +11,13 @@ from __future__ import annotations
 
 import functools
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import out_struct, resolve_interpret
 
 
 DEFAULT_BR = 128     # rows per tile (tokens)
@@ -51,7 +55,7 @@ def _ffn_kernel(h_ref, wu_ref, wg_ref, wd_ref, out_ref, *, act_name):
                    static_argnames=("act_name", "br", "bf", "interpret"))
 def expert_ffn(h, w_up, w_gate, w_down, act_name: str = "silu", *,
                br: int = DEFAULT_BR, bf: int = DEFAULT_BF,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """h: [E, R, d]; w_up/w_gate: [E, d, F]; w_down: [E, F, d]."""
     E, R, d = h.shape
     F = w_up.shape[-1]
@@ -69,6 +73,6 @@ def expert_ffn(h, w_up, w_gate, w_down, act_name: str = "silu", *,
             pl.BlockSpec((1, bf_, d), lambda e, r, f: (e, f, 0)),
         ],
         out_specs=pl.BlockSpec((1, br_, d), lambda e, r, f: (e, r, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, R, d), h.dtype),
-        interpret=interpret,
+        out_shape=out_struct((E, R, d), h.dtype, h, w_up, w_gate, w_down),
+        interpret=resolve_interpret(interpret),
     )(h, w_up, w_gate, w_down)

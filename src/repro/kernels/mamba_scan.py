@@ -5,66 +5,65 @@ SSM memory term: the [bd, N] state still round-trips HBM every token.
 This kernel is the real fix — the Mamba-kernel insight on TPU:
 
 * grid (B, d_inner/bd, S/bs), with the sequence axis innermost
-  (sequential); the running state h [bd, N] lives in a revisited output
-  block, so it touches HBM once per CHUNK instead of once per token;
+  (sequential); the running state h [N, bd] lives in VMEM scratch for
+  the whole sequence and never touches HBM;
 * the per-step tensors da = exp(dt·A) and dbx = dt·x·B are fused in
   VMEM — the [B,S,di,N] intermediates of the jnp path (6.7 GB/seq at
   32k for hymba) are never materialized.
 
-HBM traffic per chunk ≈ inputs (dt, x, B, C tiles) + y tile + state
-once: ~(3·bs·bd + 2·bs·N + bd·N) floats vs the naive scan's
+HBM traffic per chunk ≈ inputs (dt, x, B, C tiles) + y tile:
+~(3·bs·bd + 2·bs·N) floats vs the naive scan's
 ~3·bs·bd·N — a ×N/~16 reduction for hymba's N=16.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import out_struct, resolve_interpret
 
 
 DEFAULT_BD = 256     # d_inner tile
 DEFAULT_BS = 256     # sequence chunk
 
 
-def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, y_ref, h_ref, *,
-                  bs, bd, n):
-    # NOTE: refs are only ever indexed with slices ([...] / pl.dslice) —
-    # integer ref indices break the state-discharge rules of older
-    # pallas releases the compat story covers (DESIGN.md §5).
-    s_idx = pl.program_id(2)
-
-    @pl.when(s_idx == 0)
+def _mamba_kernel(dt_ref, x_ref, bt_ref, ct_ref, at_ref, y_ref, h_ref, *,
+                  bs):
+    """dt/x/y: [1, bs, bd]; bt/ct: [1, N, bs] (B and C transposed);
+    at: [N, bd]; h: [N, bd] f32 scratch carried across the sequence grid
+    axis. The state keeps d_inner on lanes, so every per-step tensor is
+    a [1, bd] row or an [N, bd] tile."""
+    @pl.when(pl.program_id(2) == 0)
     def init():
-        h_ref[...] = jnp.zeros((1, bd, n), jnp.float32)
+        h_ref[...] = jnp.zeros(h_ref.shape, jnp.float32)
 
-    a = a_ref[...].astype(jnp.float32)                 # [bd, N]
-    dt = dt_ref[...].astype(jnp.float32)               # [1, bs, bd] (VMEM)
-    x = x_ref[...].astype(jnp.float32)
-    bm = b_ref[...].astype(jnp.float32)                # [1, bs, N]
-    cm = c_ref[...].astype(jnp.float32)
+    a = at_ref[...].astype(jnp.float32)                # [N, bd]
+    bm = bt_ref[0].astype(jnp.float32)                 # [N, bs]
+    cm = ct_ref[0].astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, bm.shape, 1)
 
     def step(i, h):
-        dt_i = dt[0, i]                                # [bd]
-        x_i = x[0, i]                                  # [bd]
-        b_i = bm[0, i]                                 # [N]
-        c_i = cm[0, i]                                 # [N]
-        da = jnp.exp(dt_i[:, None] * a)                # [bd, N]
-        dbx = (dt_i * x_i)[:, None] * b_i[None, :]
-        h = da * h + dbx
-        y_i = jnp.sum(h * c_i[None, :], axis=1)        # [bd]
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None)),
-                 y_i[None, None].astype(y_ref.dtype))
+        dt_i = dt_ref[0, pl.ds(i, 1), :].astype(jnp.float32)   # [1, bd]
+        x_i = x_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        sel = lane == i
+        b_i = jnp.sum(jnp.where(sel, bm, 0.0), axis=1, keepdims=True)
+        c_i = jnp.sum(jnp.where(sel, cm, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dt_i * a) * h + (dt_i * x_i) * b_i          # [N, bd]
+        y_ref[0, pl.ds(i, 1), :] = jnp.sum(
+            h * c_i, axis=0, keepdims=True).astype(y_ref.dtype)
         return h
 
-    h_out = jax.lax.fori_loop(0, bs, step, h_ref[...][0])
-    h_ref[...] = h_out[None]
+    h_ref[...] = jax.lax.fori_loop(0, bs, step, h_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bd", "bs", "interpret"))
 def mamba_scan(dt, x, bmat, cmat, a, *, bd: int = DEFAULT_BD,
-               bs: int = DEFAULT_BS, interpret: bool = True):
+               bs: int = DEFAULT_BS, interpret: Optional[bool] = None):
     """Fused selective-SSM scan.
 
     dt:   [B, S, di]  (post-softplus step sizes)
@@ -78,25 +77,18 @@ def mamba_scan(dt, x, bmat, cmat, a, *, bd: int = DEFAULT_BD,
     N = bmat.shape[-1]
     bd_, bs_ = min(bd, di), min(bs, S)
     assert di % bd_ == 0 and S % bs_ == 0
-    grid = (B, di // bd_, S // bs_)
-    y, _ = pl.pallas_call(
-        functools.partial(_mamba_kernel, bs=bs_, bd=bd_, n=N),
-        grid=grid,
+    return pl.pallas_call(
+        functools.partial(_mamba_kernel, bs=bs_),
+        grid=(B, di // bd_, S // bs_),
         in_specs=[
             pl.BlockSpec((1, bs_, bd_), lambda b, d, s: (b, s, d)),  # dt
             pl.BlockSpec((1, bs_, bd_), lambda b, d, s: (b, s, d)),  # x
-            pl.BlockSpec((1, bs_, N), lambda b, d, s: (b, s, 0)),    # B
-            pl.BlockSpec((1, bs_, N), lambda b, d, s: (b, s, 0)),    # C
-            pl.BlockSpec((bd_, N), lambda b, d, s: (d, 0)),          # a
+            pl.BlockSpec((1, N, bs_), lambda b, d, s: (b, 0, s)),    # B^T
+            pl.BlockSpec((1, N, bs_), lambda b, d, s: (b, 0, s)),    # C^T
+            pl.BlockSpec((N, bd_), lambda b, d, s: (0, d)),          # a^T
         ],
-        out_specs=(
-            pl.BlockSpec((1, bs_, bd_), lambda b, d, s: (b, s, d)),  # y
-            pl.BlockSpec((1, bd_, N), lambda b, d, s: (b, d, 0)),    # h
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, S, di), dt.dtype),
-            jax.ShapeDtypeStruct((B, di, N), jnp.float32),
-        ),
-        interpret=interpret,
-    )(dt, x, bmat, cmat, a)
-    return y
+        out_specs=pl.BlockSpec((1, bs_, bd_), lambda b, d, s: (b, s, d)),
+        out_shape=out_struct((B, S, di), dt.dtype, dt, x, bmat, cmat, a),
+        scratch_shapes=[pltpu.VMEM((N, bd_), jnp.float32)],
+        interpret=resolve_interpret(interpret),
+    )(dt, x, bmat.transpose(0, 2, 1), cmat.transpose(0, 2, 1), a.T)

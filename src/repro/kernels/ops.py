@@ -1,13 +1,12 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU the kernels execute with ``interpret=True`` (the kernel body runs
-in Python — correctness validation); on TPU ``interpret=False`` compiles
-the real Mosaic kernels. ``repro.kernels.ref`` holds the pure-jnp oracles
+Each kernel resolves ``interpret`` by platform
+(:func:`repro.kernels.resolve_interpret`): on CPU the kernel body runs
+in the Pallas interpreter (correctness validation), on TPU the Mosaic
+kernel is compiled. ``repro.kernels.ref`` holds the pure-jnp oracles
 used by the allclose tests.
 """
 from __future__ import annotations
-
-import jax
 
 from repro.kernels import condense as _condense
 from repro.kernels import expert_ffn as _expert_ffn
@@ -15,41 +14,31 @@ from repro.kernels import similarity as _similarity
 from repro.kernels import ref  # noqa: F401 (re-export for convenience)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def masked_similarity(x, mask, **kw):
-    # backend detection lives in the kernel itself (interpret=None)
     return _similarity.masked_similarity(x, mask, **kw)
 
 
 def expert_ffn(h, w_up, w_gate, w_down, act="silu", **kw):
     act_name = act if isinstance(act, str) else \
         getattr(act, "__name__", "silu")
-    kw.setdefault("interpret", _interpret())
     return _expert_ffn.expert_ffn(h, w_up, w_gate, w_down,
                                   act_name=act_name, **kw)
 
 
 def gather_rows(y, rep_idx, **kw):
-    kw.setdefault("interpret", _interpret())
     return _condense.gather_rows(y, rep_idx, **kw)
 
 
 def pack_quantize(x, tok, **kw):
     from repro.kernels import pack as _pack
-    kw.setdefault("interpret", _interpret())
     return _pack.pack_quantize(x, tok, **kw)
 
 
 def flash_attention(q, k, v, **kw):
     from repro.kernels import flash_attn as _fa
-    kw.setdefault("interpret", _interpret())
     return _fa.flash_attention(q, k, v, **kw)
 
 
 def mamba_scan(dt, x, bmat, cmat, a, **kw):
     from repro.kernels import mamba_scan as _ms
-    kw.setdefault("interpret", _interpret())
     return _ms.mamba_scan(dt, x, bmat, cmat, a, **kw)

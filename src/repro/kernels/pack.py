@@ -6,9 +6,10 @@ wire buffer (the gate mask folded into the slot map), then a cast pass,
 then (for f8) a block-scale pass. Given the inverse slot→token map
 (``tok``, −1 = empty slot — cheap to build, it is an int scatter with no
 ``d``-wide payload), the whole thing is one gather-shaped pass: each
-program packs a block of wire slots by gathering the full-residency
-token table, masks empty slots to zero rows and writes the wire-dtype
-payload — plus the per-``SCALE_BLOCK`` f32 scale sideband for f8e4m3 —
+block of wire slots is gathered from the token table (the tiled row
+gather of :mod:`repro.kernels.condense`; the table stays in HBM),
+empty slots are masked to zero rows, and the wire-dtype payload — plus
+the per-``SCALE_BLOCK`` f32 scale sideband for f8e4m3 — is written
 directly.
 
 Bit-compatibility contract: the gather form equals the historical
@@ -22,95 +23,75 @@ not allclose targets.
 from __future__ import annotations
 
 import functools
-import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.comm import dtypes as wdt
+from repro.kernels import out_struct, resolve_interpret
+from repro.kernels.condense import (block_rows, gather_rows, gather_specs,
+                                    gather_step, tile_view)
 
 DEFAULT_BT = 256
 
 
-def _pack_cast_kernel(idx_ref, src_ref, q_ref):
-    """idx: [bt] int32 slot→token (−1 empty); src: [T, d] (full
-    residency); q: [bt, d] at the wire dtype."""
-    idx = idx_ref[...]
-    rows = src_ref[jnp.maximum(idx, 0)]
-    rows = jnp.where((idx >= 0)[:, None], rows, jnp.zeros_like(rows))
-    q_ref[...] = rows.astype(q_ref.dtype)
+def _pack_quant_kernel(idx_ref, tile_ref, q_ref, sct_ref, tile32, rows, *,
+                       block: int):
+    """f8 variant: gather + mask row by row, then per-``block`` scales
+    once the block of rows is complete. q: [bt, d_pad] f8; sct:
+    [d_pad/block, bt] f32 (scales transposed, so the per-block
+    reduction runs over sublanes). Formula mirrors
+    repro.comm.dtypes.quantize_rows exactly."""
+    gather_step(idx_ref, tile_ref, tile32, rows)
 
-
-def _pack_quant_kernel(idx_ref, src_ref, q_ref, sc_ref, *, block: int):
-    """f8 variant: same gather+mask, then per-``block`` scales.
-    src: [T, d_pad] (pre-padded); q: [bt, d_pad] f8; sc: [bt, d_pad/block]
-    f32. Formula mirrors repro.comm.dtypes.quantize_rows exactly."""
-    idx = idx_ref[...]
-    rows = src_ref[jnp.maximum(idx, 0)].astype(jnp.float32)
-    rows = jnp.where((idx >= 0)[:, None], rows, jnp.zeros_like(rows))
-    bt, dp = rows.shape
-    blocks = rows.reshape(bt, dp // block, block)
-    amax = jnp.max(jnp.abs(blocks), axis=-1)
-    # reciprocal multiply, like dtypes.quantize_rows (bitwise contract)
-    scale = jnp.where(amax > 0, amax * (1.0 / wdt.F8_MAX), 1.0) \
-        .astype(jnp.float32)
-    q_ref[...] = (blocks / scale[..., None]).reshape(bt, dp) \
-        .astype(q_ref.dtype)
-    sc_ref[...] = scale
-
-
-def _block_rows(R: int, bt: int) -> int:
-    bt_ = min(bt, R)
-    if R % bt_:
-        bt_ = math.gcd(R, bt_)
-    return bt_
+    @pl.when(pl.program_id(1) == rows.shape[0] - 1)
+    def quantize():
+        bt, dp = rows.shape
+        blocks = rows[...].T.reshape(dp // block, block, bt)
+        amax = jnp.max(jnp.abs(blocks), axis=1)             # [nb, bt]
+        # reciprocal multiply, like dtypes.quantize_rows (bitwise contract)
+        scale = jnp.where(amax > 0, amax * (1.0 / wdt.F8_MAX), 1.0) \
+            .astype(jnp.float32)
+        q = (blocks / scale[:, None, :]).reshape(dp, bt).T
+        q_ref[...] = q.astype(q_ref.dtype)
+        sct_ref[...] = scale
 
 
 @functools.partial(jax.jit, static_argnames=("wire_dtype", "bt",
                                              "interpret"))
 def pack_quantize(x, tok, *, wire_dtype: str = "f32",
-                  bt: int = DEFAULT_BT, interpret: bool = True):
+                  bt: int = DEFAULT_BT, interpret: Optional[bool] = None):
     """x: [T, d] source rows; tok: [R] int32 slot→token map (−1 empty).
     Returns ``(q, scales)``: ``q`` [R, d] at the wire dtype (``[R,
     d_pad]`` for f8, padded to whole scale blocks), ``scales`` [R,
     d_pad/32] f32 for f8 else None — exactly
     :func:`repro.comm.dtypes.quantize_rows` of the packed rows."""
-    T, d = x.shape
+    if wire_dtype != "f8e4m3":
+        out_dt = x.dtype if wire_dtype == "f32" else jnp.bfloat16
+        return gather_rows(x, tok, out_dtype=out_dt, bt=bt,
+                           interpret=interpret), None
+    _, d = x.shape
     R = tok.shape[0]
-    bt_ = _block_rows(R, bt)
-    if wire_dtype == "f8e4m3":
-        d_pad = wdt.pad_to_block(d)
-        if d_pad != d:
-            x = jnp.pad(x, ((0, 0), (0, d_pad - d)))
-        nb = d_pad // wdt.SCALE_BLOCK
-        return pl.pallas_call(
-            functools.partial(_pack_quant_kernel, block=wdt.SCALE_BLOCK),
-            grid=(R // bt_,),
-            in_specs=[
-                pl.BlockSpec((bt_,), lambda i: (i,)),
-                pl.BlockSpec((T, d_pad), lambda i: (0, 0)),
-            ],
+    bt_ = block_rows(R, bt)
+    d_pad = wdt.pad_to_block(d)
+    if d_pad != d:
+        x = jnp.pad(x, ((0, 0), (0, d_pad - d)))
+    nb = d_pad // wdt.SCALE_BLOCK
+    src, scratch = gather_specs(bt_, x)
+    q, sct = pl.pallas_call(
+        functools.partial(_pack_quant_kernel, block=wdt.SCALE_BLOCK),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R // bt_, bt_), in_specs=[src],
             out_specs=[
-                pl.BlockSpec((bt_, d_pad), lambda i: (i, 0)),
-                pl.BlockSpec((bt_, nb), lambda i: (i, 0)),
+                pl.BlockSpec((bt_, d_pad), lambda i, j, idx: (i, 0)),
+                pl.BlockSpec((nb, bt_), lambda i, j, idx: (0, i)),
             ],
-            out_shape=[
-                jax.ShapeDtypeStruct((R, d_pad), wdt._f8_dtype()),
-                jax.ShapeDtypeStruct((R, nb), jnp.float32),
-            ],
-            interpret=interpret,
-        )(tok, x)
-    out_dt = x.dtype if wire_dtype == "f32" else jnp.bfloat16
-    q = pl.pallas_call(
-        _pack_cast_kernel,
-        grid=(R // bt_,),
-        in_specs=[
-            pl.BlockSpec((bt_,), lambda i: (i,)),
-            pl.BlockSpec((T, d), lambda i: (0, 0)),   # whole source table
-        ],
-        out_specs=pl.BlockSpec((bt_, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, d), out_dt),
-        interpret=interpret,
-    )(tok, x)
-    return q, None
+            scratch_shapes=scratch),
+        out_shape=[out_struct((R, d_pad), wdt._f8_dtype(), x, tok),
+                   out_struct((nb, R), jnp.float32, x, tok)],
+        interpret=resolve_interpret(interpret),
+    )(tok, tile_view(x))
+    return q, sct.T

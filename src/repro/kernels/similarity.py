@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import out_struct, resolve_interpret
+
 
 DEFAULT_BG = 128      # output tile edge (MXU-aligned)
 DEFAULT_BD = 512      # feature-dim slab
@@ -84,11 +86,8 @@ def masked_similarity(x, mask, *, bg: int = DEFAULT_BG,
     """x: [G, d]; mask: [G, G] bool. Returns [G, G] f32 similarity in
     [0,1], zeroed where mask is False; fully-masked tiles are skipped.
 
-    ``interpret=None`` (default) resolves by backend like the other
-    kernels: the compiled Mosaic kernel on TPU, interpreter mode
-    elsewhere. Pass an explicit bool to override (tests force True)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    ``interpret=None`` resolves by platform
+    (:func:`repro.kernels.resolve_interpret`)."""
     G, d = x.shape
     bg = min(bg, G)
     bd = min(bd, d)
@@ -113,6 +112,6 @@ def masked_similarity(x, mask, *, bg: int = DEFAULT_BG,
             pl.BlockSpec((bg, bg), lambda i, j: (i, j)),         # mask
         ],
         out_specs=pl.BlockSpec((bg, bg), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((G, G), jnp.float32),
-        interpret=interpret,
+        out_shape=out_struct((G, G), jnp.float32, x, mask),
+        interpret=resolve_interpret(interpret),
     )(mask_any, x, x, mask)

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape ×
 mesh) combination with ShapeDtypeStruct stand-ins (no allocation), record
 memory_analysis / cost_analysis / per-collective bytes for the roofline.
@@ -13,6 +10,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
@@ -669,7 +667,8 @@ def orchestrate(jobs: int, multi_pod_also: bool = True,
             logf = open(str(out) + ".log", "w")
             procs.append((subprocess.Popen(
                 cmd, stdout=logf, stderr=subprocess.STDOUT,
-                env={**os.environ, "PYTHONPATH": "src"},
+                env={**os.environ, "PYTHONPATH": "src",
+                     "JAX_PLATFORMS": "cpu"},
                 cwd=str(ARTIFACTS.parents[1])), arch, shape, mp, out, logf,
                 time.time()))
         still = []
@@ -768,6 +767,13 @@ def main():
                          "unified metrics record (repro.obs.metrics "
                          "JSONL) to this path")
     args = ap.parse_args()
+    # the production meshes are built from 512 placeholder host devices;
+    # the flag must be in place before JAX starts its CPU backend
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512")))
     from repro.config import resolve_pipeline_chunks
     if args.all:
         orchestrate(args.jobs)

@@ -64,7 +64,8 @@ def main(jobs: int = 4):
             cmd = [sys.executable, "-m", "repro.launch.dryrun",
                    "--arch", arch, "--shape", shape, "--out", str(out),
                    "--variant", var] + extra
-            full_env = {**os.environ, "PYTHONPATH": "src", **env}
+            full_env = {**os.environ, "PYTHONPATH": "src",
+                        "JAX_PLATFORMS": "cpu", **env}
             logf = open(str(out) + ".log", "w")
             procs.append((subprocess.Popen(
                 cmd, stdout=logf, stderr=subprocess.STDOUT, env=full_env,

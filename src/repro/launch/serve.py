@@ -237,6 +237,9 @@ def main():
     from repro.dist import DistContext, make_dist, single_device
     from repro.launch.mesh import make_host_mesh
     from repro.models.model import build_model
+    from repro.launch.device import device_banner, enable_compile_cache
+    device_banner()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

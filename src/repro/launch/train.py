@@ -3,6 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch olmoe-1b-7b \
         --steps 200 --reduced --mesh host --model-axis 4
 
+``main(argv)`` is callable in-process (``chip_smoke.py`` drives it that
+way) and returns the run's losses, step times and compile times.
+
 Runs the full production stack: mesh + sharded params, LUFFY (adaptive
 condensation threshold with host-side rate-bucket switching — one
 compiled executable per bucket, cached), AdamW/Adafactor, checkpointing,
@@ -19,14 +22,17 @@ import time
 from pathlib import Path
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="moe-gpt2")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--reduced", action="store_true",
                     help="smoke-scale variant of the arch (CPU)")
     ap.add_argument("--d-model", type=int, default=256)
-    ap.add_argument("--layers", type=int, default=2)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth: with --reduced the smoke depth "
+                         "(default 2); without it, cut the arch to this "
+                         "many layers and keep every width")
     ap.add_argument("--experts", type=int, default=0,
                     help="override expert count (reduced mode)")
     ap.add_argument("--global-batch", type=int, default=0)
@@ -161,10 +167,13 @@ def main():
     ap.add_argument("--drift-k", type=int, default=5,
                     help="consecutive out-of-tolerance steps before the "
                          "drift detector fires")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     import jax
     import jax.numpy as jnp
+    from repro.launch.device import device_banner, enable_compile_cache
+    device = device_banner()
+    enable_compile_cache()
     from repro import checkpoint, optim, train_lib
     from repro.config import (LuffyConfig, OptimConfig, ShapeConfig,
                               reduced)
@@ -177,9 +186,14 @@ def main():
 
     cfg = get_config(args.arch)
     if args.reduced:
-        cfg = reduced(cfg, num_layers=args.layers, d_model=args.d_model,
+        cfg = reduced(cfg, num_layers=args.layers or 2,
+                      d_model=args.d_model,
                       max_experts=args.experts or 4,
                       seq_len_hint=args.seq_len)
+    elif args.layers:
+        print(f"depth cut: {args.arch} {cfg.num_layers} -> {args.layers} "
+              f"layers (widths unchanged)")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     gb = args.global_batch or (8 if args.reduced else 256)
     shape = ShapeConfig("train", args.seq_len, gb, "train")
 
@@ -296,6 +310,14 @@ def main():
     if dist.enabled:
         params = jax.device_put(
             params, jax.tree.map(lambda s: dist.sharding(s), pspecs))
+    expert_shard = None
+    if cfg.uses_moe:
+        w_up = next(leaf for path, leaf in
+                    jax.tree_util.tree_leaves_with_path(params)
+                    if "['experts']['w_up']" in jax.tree_util.keystr(path))
+        expert_shard = list(w_up.addressable_shards[0].data.shape)
+        print(f"experts w_up {list(w_up.shape)}: per-device shard "
+              f"{expert_shard}")
     opt_state = optim.init_opt_state(params, ocfg)
     # cross-step wire error feedback (DESIGN.md §15): allocate the
     # residual buffer only when a lossy wire can produce one
@@ -306,17 +328,32 @@ def main():
         tf_mod.wire_ef_shape(cfg, gb, args.seq_len) if use_ef else None)
     data = SyntheticLM(cfg, shape)
 
-    # one executable per condensation rate bucket, compiled on demand
+    # one executable per condensation rate bucket, compiled ahead of
+    # its first step so that compile time stays out of the step times
     steps_by_bucket = {}
+    compile_s = []
 
-    def get_step(bucket: int):
+    def get_step(bucket: int, batch):
         if bucket not in steps_by_bucket:
             cap = (train_lib.capacity_for_bucket(cfg, shape, dist, luffy,
                                                  bucket)
                    if cfg.uses_moe else 8)
             fn = train_lib.make_train_step(cfg, luffy, ocfg, dist, cap,
                                            param_pspecs=pspecs)
-            steps_by_bucket[bucket] = jax.jit(fn)
+            t0 = time.perf_counter()
+            # params and optimizer state are rebound to the step's
+            # outputs, so their input buffers are donated
+            steps_by_bucket[bucket] = jax.jit(fn, donate_argnums=(0, 1)) \
+                .lower(params, opt_state, lstate, batch).compile()
+            compile_s.append(time.perf_counter() - t0)
+            ma = steps_by_bucket[bucket].memory_analysis()
+            mem = ("" if ma is None else
+                   f" memory: argument={ma.argument_size_in_bytes}B "
+                   f"output={ma.output_size_in_bytes}B "
+                   f"temp={ma.temp_size_in_bytes}B "
+                   f"alias={ma.alias_size_in_bytes}B")
+            print(f"compile bucket={bucket} {compile_s[-1]}s{mem}",
+                  flush=True)
         return steps_by_bucket[bucket]
 
     # step tracing (DESIGN.md §11): fenced spans around the jitted step;
@@ -350,16 +387,19 @@ def main():
 
     bucket = 0
     log = []
+    step_s = []
     t_start = time.time()
     observed_rate = 0.0
     for i in range(args.steps):
         with obs_trace.phase("data", cat="step"):
             batch = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
-        t0 = time.time()
-        with obs_trace.phase("step", cat="step", step=i) as _sp:
-            out = get_step(bucket)(params, opt_state, lstate, batch)
-            params, opt_state, lstate, m = _sp.fence(out)
-        dt = time.time() - t0
+        step_fn = get_step(bucket, batch)
+        t0 = time.perf_counter()
+        with obs_trace.phase("step", cat="step", step=i):
+            params, opt_state, lstate, m = jax.block_until_ready(
+                step_fn(params, opt_state, lstate, batch))
+        dt = time.perf_counter() - t0
+        step_s.append(dt)
         m = train_lib.finalize_metrics(m, luffy)
         observed_rate = 0.8 * observed_rate + 0.2 * m["condense_rate"]
         if cfg.uses_moe and luffy.enable_condensation and i >= 3:
@@ -367,7 +407,7 @@ def main():
         extra = {}
         step_ms = dt * 1e3
         if expected_step_ms is None:
-            if i >= 1:                 # step 0 is compile time
+            if i >= 1:                 # step 0 warms the caches
                 warmup_ms.append(step_ms)
             if len(warmup_ms) >= 3:
                 expected_step_ms = sum(warmup_ms) / len(warmup_ms)
@@ -409,8 +449,7 @@ def main():
                 monitor.reset()
                 expected_step_ms = None
                 warmup_ms.clear()
-        rec = registry.observe(i, m, time_s=round(dt, 3), bucket=bucket,
-                               **extra)
+        rec = registry.observe(i, m, time_s=dt, bucket=bucket, **extra)
         log.append(rec)
         if args.metrics_json:
             obs_metrics.write_jsonl(args.metrics_json, rec)
@@ -423,11 +462,15 @@ def main():
                   f"cond={m['condense_rate']:.2f} bucket={bucket} "
                   f"local={m['local_frac']:.2f} "
                   f"drop=({m['dispatch_drop']:.3f},{m['combine_drop']:.3f})"
-                  f"{inter} {dt:.2f}s", flush=True)
+                  f"{inter} {dt * 1e3:.3f}ms", flush=True)
         if args.ckpt and args.ckpt_every and (i + 1) % args.ckpt_every == 0:
             checkpoint.save(args.ckpt, params, pspecs=pspecs, step=i + 1)
     print(f"done: {args.steps} steps in {time.time()-t_start:.1f}s; "
           f"final loss {log[-1]['metrics']['train/loss']:.4f}")
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get("peak_bytes_in_use")
+    if peak is not None:
+        print(f"peak_bytes_in_use {peak}")
     if args.ckpt:
         checkpoint.save(args.ckpt, params, pspecs=pspecs, step=args.steps)
     if args.log_file:
@@ -462,6 +505,11 @@ def main():
         print(f"trace: {len(tracer.events)} events -> {trace_out} "
               f"(step total {steps.get('total_us', 0.0)/1e3:.1f}ms over "
               f"{steps.get('count', 0)} spans)")
+    return {"device": device, "layers": cfg.num_layers,
+            "expert_shard": expert_shard,
+            "losses": [r["metrics"]["train/loss"] for r in log],
+            "step_s": step_s, "compile_s": compile_s,
+            "peak_bytes_in_use": peak}
 
 
 if __name__ == "__main__":

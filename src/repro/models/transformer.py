@@ -187,7 +187,7 @@ def _attn_seqpar(p, cfg, xn, positions, layer_idx, *, causal, dist,
     pos_spec = P(bax, sax)
     p_specs = jax.tree.map(lambda _: P(), p)
     kvv = kv_valid
-    fn = rcomm.shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(p_specs, x_spec, pos_spec,
                   pos_spec if kvv is not None else P(),
@@ -246,13 +246,6 @@ def _token_mixer_full(p, cfg, x, positions, layer_idx, *, causal, enc_out,
     return x, out_kv
 
 
-def _pmean_all(v, axes):
-    """pmean over all mesh axes regardless of the value's varying state
-    (replicated-over-model decode aux scalars otherwise fail the vma
-    check on new jax; see repro.comm.compat.pmean_all)."""
-    return rcomm.pmean_all(v, axes)
-
-
 def _moe_apply_dist(p_moe, x, sideband, s_prev, threshold, cfg, luffy,
                     dist: DistContext, mode: str, capacity: int,
                     plan_carry=None, cond_carry=None, plan_template=None,
@@ -309,10 +302,10 @@ def _moe_apply_dist(p_moe, x, sideband, s_prev, threshold, cfg, luffy,
                 fsdp_axes=fsdp if use_2d else None,
                 batch_sharded=batch_sharded,
                 overlap=luffy.exec_mode == "decode_overlap")
-            aux = jax.tree.map(lambda a: _pmean_all(a, all_axes), aux)
+            aux = jax.tree.map(lambda a: rcomm.pmean_all(a, all_axes), aux)
             return y, aux
 
-        fn = rcomm.shard_map(
+        fn = jax.shard_map(
             inner_dec, mesh=mesh,
             in_specs=(moe_specs, P(bax, None, None)),
             out_specs=(P(bax, None, None),
@@ -396,7 +389,7 @@ def _moe_apply_dist(p_moe, x, sideband, s_prev, threshold, cfg, luffy,
             wire_ef=(efp if has_ef else None))
         if has_ef and ef2 is not None:
             efp = ef2
-        aux = jax.tree.map(lambda a: _pmean_all(a, all_axes), aux)
+        aux = jax.tree.map(lambda a: rcomm.pmean_all(a, all_axes), aux)
         if s_next is None:
             s_next = jnp.zeros((1,), jnp.float32)    # placeholder
         else:
@@ -439,7 +432,7 @@ def _moe_apply_dist(p_moe, x, sideband, s_prev, threshold, cfg, luffy,
                 cond_carry["valid"]) if has_cc else (zpi, zpi, zp, zp))
     ef_spec = x_spec if has_ef else P()
     ef_arg = wire_ef if has_ef else jnp.zeros((1, 1, 1), jnp.float32)
-    fn = rcomm.shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(moe_specs, x_spec, lbl_spec, len_spec, sp_in, P(),
                   pc_counts_spec, pc_lens_spec, P(),

@@ -208,26 +208,24 @@ def _a2a_fn(mesh, axis: str, chain: int = 1):
     over ``axis``."""
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.comm import compat
 
     def f(x):
         for _ in range(chain):
             x = jax.lax.all_to_all(x, axis, split_axis=0, concat_axis=0,
                                    tiled=True)
         return x
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(axis),
-                                    out_specs=P(axis)))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P(axis)))
 
 
 def _psum_fn(mesh, axis: str):
     import jax
     from jax.sharding import PartitionSpec as P
-    from repro.comm import compat
 
     def f(x):
         return jax.lax.psum(x, axis)
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P(axis),
-                                    out_specs=P()))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(axis),
+                                 out_specs=P()))
 
 
 def _payload(mesh, axis: str, rows: int, d: int):

@@ -52,35 +52,27 @@ def _now_us() -> float:
 
 def _trace_state_clean() -> bool:
     """True when NOT inside a jax trace (jit/scan/shard_map body) — the
-    only place a host-side timestamp means anything. Falls back to True
-    when the introspection API is unavailable (or jax is not imported
-    at all: pure host spans are always fine)."""
+    only place a host-side timestamp means anything. Always True when
+    jax is not imported at all (pure host spans)."""
     import sys
-    jax = sys.modules.get("jax")
-    if jax is None:
+    if "jax" not in sys.modules:
         return True
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return True
+    from jax._src import core
+    return core.trace_state_clean()
 
 
 def _block(value):
-    """``jax.block_until_ready`` that tolerates non-array / abstract
-    leaves (fencing must never change program behavior)."""
+    """``block_until_ready`` on every concrete array leaf of ``value``;
+    abstract tracers and non-array leaves are skipped, and an error the
+    device raises propagates to the caller."""
     import sys
     jax = sys.modules.get("jax")
     if jax is None:
         return value
-    try:
-        leaves = jax.tree.leaves(value)
-        for leaf in leaves:
-            if isinstance(leaf, jax.core.Tracer):
-                continue
-            if hasattr(leaf, "block_until_ready"):
-                leaf.block_until_ready()
-    except Exception:
-        pass
+    for leaf in jax.tree.leaves(value):
+        if not isinstance(leaf, jax.core.Tracer) \
+                and hasattr(leaf, "block_until_ready"):
+            leaf.block_until_ready()
     return value
 
 

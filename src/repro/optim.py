@@ -26,8 +26,10 @@ def init_opt_state(params, cfg: OptimConfig) -> OptState:
     second-moment estimates) — the memory-viable choice for 100B+ MoE
     (full f32 Adam moments for llama4-400b are 24 GB/device at maximal
     sharding on a 256-chip pod; factored states are ~params/4096)."""
+    # zeros_like keeps each parameter's sharding: the moments of
+    # expert-sharded weights are sharded the same way
     if cfg.name == "adafactor":
-        mu = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.bfloat16), params)
+        mu = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.bfloat16), params)
 
         def nu_init(p):
             if _factored(p):
@@ -38,8 +40,7 @@ def init_opt_state(params, cfg: OptimConfig) -> OptState:
 
         return OptState(jnp.zeros((), jnp.int32), mu,
                         jax.tree.map(nu_init, params))
-    zeros = jax.tree.map(
-        lambda p: jnp.zeros(p.shape, jnp.float32), params)
+    zeros = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
     return OptState(jnp.zeros((), jnp.int32), zeros,
                     jax.tree.map(jnp.copy, zeros))
 

@@ -37,7 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.comm import CommContext, compat
+from repro.comm import CommContext
 from repro.comm import dtypes as wdt
 from repro.comm import ledger as comm_ledger
 from repro.condense import plan as cplan
@@ -728,7 +728,7 @@ def execute_plan(params, x: Array, sideband: Dict[str, Array],
         assert plan.replica_src is None, plan.objective
         dchunks = None
         if plan.pipelined:
-            L_loc = compat.axis_size(comm.local_axis)
+            L_loc = jax.lax.axis_size(comm.local_axis)
             dchunks = plan_unique_chunks(
                 cwire.dedup_capacity(T, E_local, L_loc, C),
                 plan.chunks.n_chunks)
@@ -857,7 +857,7 @@ def execute_plan(params, x: Array, sideband: Dict[str, Array],
     # zero weights, so its (empty) rows produce exact zeros
     ew = params["experts"]
     if has_lane:
-        L_loc = compat.axis_size(comm.local_axis)
+        L_loc = jax.lax.axis_size(comm.local_axis)
         src = plan.replica_src[my]
         src_safe = jnp.maximum(src, 0)
         owner_row = (src_safe // E_local) % L_loc * E_local \

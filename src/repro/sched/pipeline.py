@@ -15,8 +15,7 @@ consumed and the one in flight — so peak buffer memory is ``2/n`` of the
 sync path's. XLA lowers the collectives to async start/done pairs; the
 program-order interleaving above gives the latency-hiding scheduler a
 compute region to sink each ``done`` past. An optimization barrier
-(``repro.comm.compat.optimization_barrier`` — differentiable shim)
-ties each issued next-chunk payload to the current chunk's payload so the
+(``jax.lax.optimization_barrier``, which differentiates natively) ties each issued next-chunk payload to the current chunk's payload so the
 scheduler cannot "helpfully" defer the next collective until after the
 current compute (the same reason the attention path barriers its K/V
 gathers; see ``models/transformer.py``).
@@ -29,7 +28,7 @@ from __future__ import annotations
 
 from typing import Callable, List, NamedTuple, Optional, Tuple
 
-from repro.comm import compat
+import jax
 
 
 class Stage(NamedTuple):
@@ -100,7 +99,7 @@ def run_pipeline(n_chunks: int, *,
             prev = st.chunk - 1
             if barrier and prev in payloads:
                 payloads[st.chunk], payloads[prev] = \
-                    compat.optimization_barrier(
+                    jax.lax.optimization_barrier(
                         (payloads[st.chunk], payloads[prev]))
         elif st.name == "compute":
             computed[st.chunk] = compute(st.chunk, payloads.pop(st.chunk))

@@ -187,8 +187,9 @@ def _run(script_body: str) -> str:
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
+        from jax import shard_map
         from repro.comm import (CommContext, Topology, hier_all_to_all,
-                                make_mesh, shard_map)
+                                make_mesh)
     """) + textwrap.dedent(script_body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")

@@ -5,7 +5,6 @@ The ledger JSON is a consumed artifact (benchmarks, CI uploads, the
 ``schema_version`` and the exact key sets of every section. Renaming or
 adding a key MUST bump ``repro.obs.metrics.COMM_LEDGER_SCHEMA_VERSION``
 and update the goldens here."""
-import os
 import types
 
 import numpy as np
@@ -14,15 +13,7 @@ import pytest
 from repro.config import SHAPES
 from repro.configs import get_config
 
-# importing the dryrun launcher sets XLA_FLAGS for its own 512-device
-# use; restore the suite's environment so later jax inits (in-process
-# or in subprocess tests) keep their device count
-_SAVED_XLA_FLAGS = os.environ.get("XLA_FLAGS")
-from repro.launch.dryrun import comm_traffic_ledger  # noqa: E402
-if _SAVED_XLA_FLAGS is None:
-    os.environ.pop("XLA_FLAGS", None)
-else:
-    os.environ["XLA_FLAGS"] = _SAVED_XLA_FLAGS
+from repro.launch.dryrun import comm_traffic_ledger
 from repro.obs.calibrate import Calibration, calibration_key
 from repro.obs.metrics import COMM_LEDGER_SCHEMA_VERSION
 

@@ -47,7 +47,8 @@ def test_multi_device_bijection_roundtrips_every_key():
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.comm import CommContext, make_mesh, shard_map
+        from jax import shard_map
+        from repro.comm import CommContext, make_mesh
         from repro.core.moe_layer import _exchange_sideband
 
         n_seq, S = 4, 6

@@ -245,7 +245,8 @@ def _run(script_body: str) -> str:
         import jax, jax.numpy as jnp
         import numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.comm import CommContext, Topology, make_mesh, shard_map
+        from jax import shard_map
+        from repro.comm import CommContext, Topology, make_mesh
         from repro.comm import dtypes as wdt
         from repro.configs import get_config
         from repro.config import reduced, LuffyConfig, ShapeConfig
