@@ -17,8 +17,8 @@ on faith. This benchmark runs the fit, then CHECKS it:
   topology fingerprint / bumped schema loads as a MISS, and the
   load-before-measure path returns the persisted fit verbatim;
 * the ``phase()`` trace hook costs <5% on an untraced step (the
-  ``--trace`` overhead budget: production steps pay one module-global
-  comparison per hook).
+  ``--trace`` overhead budget: an untraced host call pays a trace-state
+  check and one module-global comparison per hook).
 
 Emits CSV rows and ``artifacts/fig_calibration.json``; the artifact
 itself lands in ``artifacts/calib/<key>.calib.json``.

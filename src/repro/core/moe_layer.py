@@ -242,8 +242,9 @@ def moe_core_planned(params, x, sideband: Dict[str, Array],
     n_seq, S, d = x.shape
     xf = x.reshape(n_seq * S, d)
     xn = _rms(xf, params["norm"]["scale"]).astype(_dtype(cfg.compute_dtype))
-    gate = gate_apply(params["router"], xn, cfg.moe.top_k)
     from repro.obs import trace as obs_trace
+    with obs_trace.phase("router"):
+        gate = gate_apply(params["router"], xn, cfg.moe.top_k)
     with obs_trace.phase("plan_build") as _sp:
         if plan_template is not None:
             inst = (instantiate_decode_plan if plan_template.mode == "decode"

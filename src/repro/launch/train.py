@@ -132,7 +132,9 @@ def main(argv=None):
                     help="step tracing (repro.obs.trace): fenced spans "
                          "around every jitted step plus one eager "
                          "exchange probe for the per-phase breakdown; "
-                         "writes Chrome-trace JSON (see --trace-out)")
+                         "writes Chrome-trace JSON (see --trace-out) "
+                         "and, beside it, the JAX profiler's trace "
+                         "(<trace-out without .json>.profile/)")
     ap.add_argument("--trace-out", default="",
                     help="trace JSON path (implies --trace; default "
                          "trace.json)")
@@ -356,10 +358,13 @@ def main(argv=None):
                   flush=True)
         return steps_by_bucket[bucket]
 
-    # step tracing (DESIGN.md §11): fenced spans around the jitted step;
-    # phase spans inside the step are structural no-ops (lax.scan traces
-    # the forward), so --trace adds one eager probe_exchange at the end
-    # for the plan_build/dispatch/expert_ffn/combine breakdown
+    # step tracing (DESIGN.md §11): fenced host spans around the loop's
+    # upload, step, metrics pull and bucket choice, plus one eager
+    # probe_exchange at the end for the plan_build/dispatch/expert_ffn/
+    # combine breakdown. Inside the jitted step the phases are named
+    # scopes, so the JAX profiler's trace, written beside the Chrome
+    # JSON, names each device op's layer, with the host spans on its
+    # clock.
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
     trace_out = args.trace_out or ("trace.json" if args.trace else "")
@@ -367,6 +372,12 @@ def main(argv=None):
     if trace_out:
         tracer = obs_trace.Tracer(fence=True)
         obs_trace.activate(tracer)
+        # a cache key without metadata could load an executable compiled
+        # from the same code under other scopes, whose op_names are stale
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+        profile_dir = Path(trace_out).with_suffix(".profile")
+        jax.profiler.start_trace(str(profile_dir))
     registry = obs_metrics.MetricsRegistry(
         luffy=luffy, run_info={"arch": args.arch, "steps": args.steps,
                                "comm_mode": luffy.comm_mode,
@@ -391,7 +402,7 @@ def main(argv=None):
     t_start = time.time()
     observed_rate = 0.0
     for i in range(args.steps):
-        with obs_trace.phase("data", cat="step"):
+        with obs_trace.phase("upload", cat="step"):
             batch = {k: jnp.asarray(v) for k, v in data.batch(i).items()}
         step_fn = get_step(bucket, batch)
         t0 = time.perf_counter()
@@ -400,10 +411,13 @@ def main(argv=None):
                 step_fn(params, opt_state, lstate, batch))
         dt = time.perf_counter() - t0
         step_s.append(dt)
-        m = train_lib.finalize_metrics(m, luffy)
-        observed_rate = 0.8 * observed_rate + 0.2 * m["condense_rate"]
-        if cfg.uses_moe and luffy.enable_condensation and i >= 3:
-            bucket = train_lib.pick_bucket_host(luffy, 0.0, observed_rate)
+        with obs_trace.phase("metrics", cat="step"):
+            m = train_lib.finalize_metrics(m, luffy)
+        with obs_trace.phase("bucket", cat="step"):
+            observed_rate = 0.8 * observed_rate + 0.2 * m["condense_rate"]
+            if cfg.uses_moe and luffy.enable_condensation and i >= 3:
+                bucket = train_lib.pick_bucket_host(luffy, 0.0,
+                                                    observed_rate)
         extra = {}
         step_ms = dt * 1e3
         if expected_step_ms is None:
@@ -499,12 +513,14 @@ def main(argv=None):
             print(f"probe: {len(per_dev)} devices, "
                   f"dispersion {disp:.2f}x")
         obs_trace.deactivate()
+        jax.profiler.stop_trace()
         tracer.write(trace_out)
         summary = tracer.summary()
         steps = summary.get("step", {})
         print(f"trace: {len(tracer.events)} events -> {trace_out} "
               f"(step total {steps.get('total_us', 0.0)/1e3:.1f}ms over "
-              f"{steps.get('count', 0)} spans)")
+              f"{steps.get('count', 0)} spans); profiler trace -> "
+              f"{profile_dir}")
     return {"device": device, "layers": cfg.num_layers,
             "expert_shard": expert_shard,
             "losses": [r["metrics"]["train/loss"] for r in log],

@@ -29,6 +29,7 @@ from repro.core import moe_layer as moe
 from repro.dist import DistContext
 from repro.models import blocks as bk
 from repro.models import ssm as ssm_mod
+from repro.obs import trace as obs_trace
 
 Array = jnp.ndarray
 
@@ -209,13 +210,14 @@ def _token_mixer_full(p, cfg, x, positions, layer_idx, *, causal, enc_out,
               and cfg.attn is not None)
 
     def self_attn(xn):
-        if seqpar:
-            return _attn_seqpar(p["attn"], cfg, xn, positions, layer_idx,
-                                causal=causal, dist=dist,
-                                kv_valid=kv_valid), None
-        return bk.attn_apply(p["attn"], cfg, xn, positions,
-                             layer=layer_idx, causal=causal,
-                             kv_valid=kv_valid)
+        with obs_trace.phase("attention"):
+            if seqpar:
+                return _attn_seqpar(p["attn"], cfg, xn, positions,
+                                    layer_idx, causal=causal, dist=dist,
+                                    kv_valid=kv_valid), None
+            return bk.attn_apply(p["attn"], cfg, xn, positions,
+                                 layer=layer_idx, causal=causal,
+                                 kv_valid=kv_valid)
 
     if cfg.attn is not None and cfg.ssm is not None and cfg.parallel_ssm:
         xn = bk.norm_apply(p["attn_norm"], x, cfg.norm)
@@ -504,6 +506,11 @@ def embed_tokens(params, cfg: ModelConfig, tokens, prefix=None,
     -> local gather (d over model) -> reshard to the activation spec.
     Without staging, GSPMD replicates the batch (observed: 1.25 GiB
     [256,4096,320] buffers dominating the llama4 memory profile)."""
+    with obs_trace.phase("embed"):
+        return _embed_tokens(params, cfg, tokens, prefix, dist)
+
+
+def _embed_tokens(params, cfg: ModelConfig, tokens, prefix, dist):
     cdt = bk._dtype(cfg.compute_dtype)
     table = params["embed"]["table"]
     m_axes = () if dist is None else dist.model_axes_tuple
@@ -539,6 +546,11 @@ def chunked_xent(params, cfg, x, labels, *, chunk: int = 512):
     """Cross-entropy over S in chunks to bound logits memory.
 
     labels < 0 are ignored. Returns (sum_loss, count)."""
+    with obs_trace.phase("lm_head"):
+        return _chunked_xent(params, cfg, x, labels, chunk)
+
+
+def _chunked_xent(params, cfg, x, labels, chunk):
     B, S, _ = x.shape
     chunk = min(chunk, S)
     n = S // chunk

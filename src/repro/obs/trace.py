@@ -1,5 +1,5 @@
-"""Step tracing: low-overhead host-side spans + Chrome-trace export
-(DESIGN.md §11).
+"""Step tracing: host-side spans, Chrome-trace export, and named scopes
+on the device (DESIGN.md §11).
 
 A :class:`Tracer` records **host-timed spans** — begin/end wall-clock
 pairs with nesting — as structured events, and exports them in the
@@ -11,11 +11,23 @@ Two ways to open a span:
 * ``tracer.span("step", step=i)`` — explicit, used by the launchers
   around the jitted train/serve step (the caller holds the tracer);
 * ``phase("dispatch")`` — the module-level hook the instrumented hot
-  path (``repro.plan.exchange``) calls. It is a **no-op** unless a
-  tracer has been :func:`activate`\\ d *and* the caller is running
-  outside a jax trace (inside ``jit``/``scan``/``shard_map`` bodies the
-  Python code runs at trace time, so a host timestamp there would be
-  compile-time garbage — those spans are dropped, not recorded).
+  path (``repro.plan.exchange``, the model, the train step) calls. What
+  it returns depends on where it runs:
+
+  - inside a jax trace (``jit``/``scan``/``shard_map`` bodies, where the
+    Python code runs at trace time and a host timestamp would mean
+    nothing) it opens ``jax.named_scope(name)``: every operation traced
+    in its body carries ``name`` in its ``op_name`` metadata, through
+    the backward pass (``transpose(jvp(...))``) and rematerialisation,
+    into the compiled executable's HLO and so to each device operation
+    of a profiler trace. Scopes exist at trace and compile time only:
+    the compiled step runs as fast with them as without;
+  - outside a jax trace with a tracer :func:`activate`\\ d, it records a
+    host span and also opens ``jax.profiler.TraceAnnotation(name)``, so
+    the span lands on the JAX profiler's host plane, on the device
+    trace's clock;
+  - outside a jax trace with no tracer, it is the shared no-op
+    :data:`NULL_SPAN`.
 
 Fencing: jax dispatch is asynchronous, so a host timestamp right after
 an op returns measures *launch*, not completion. With
@@ -23,8 +35,9 @@ an op returns measures *launch*, not completion. With
 ``span.fence(value)`` calls ``jax.block_until_ready`` on the value at
 the phase boundary, making the span's duration the real device time of
 the phase (single-process backends; the fence is skipped for abstract
-tracers). Untraced runs pay only a module-global ``None`` check per
-``phase()`` call — the <5% overhead budget ``benchmarks/
+tracers). A named scope's ``fence`` and ``set`` do nothing. Untraced
+host calls pay a module-global ``None`` check and a trace-state check
+per ``phase()`` call — the <5% overhead budget ``benchmarks/
 fig_calibration.py`` asserts.
 
 Exclusive time: every completed span records ``self_us`` (duration
@@ -36,6 +49,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -54,7 +68,6 @@ def _trace_state_clean() -> bool:
     """True when NOT inside a jax trace (jit/scan/shard_map body) — the
     only place a host-side timestamp means anything. Always True when
     jax is not imported at all (pure host spans)."""
-    import sys
     if "jax" not in sys.modules:
         return True
     from jax._src import core
@@ -65,7 +78,6 @@ def _block(value):
     """``block_until_ready`` on every concrete array leaf of ``value``;
     abstract tracers and non-array leaves are skipped, and an error the
     device raises propagates to the caller."""
-    import sys
     jax = sys.modules.get("jax")
     if jax is None:
         return value
@@ -78,9 +90,10 @@ def _block(value):
 
 class _Span:
     """One open span. Context manager; records an ``"X"`` (complete)
-    event on exit."""
+    event on exit, and holds a ``jax.profiler.TraceAnnotation`` of the
+    same name open meanwhile (when jax is imported)."""
     __slots__ = ("tracer", "name", "cat", "args", "t0", "child_us",
-                 "parent")
+                 "parent", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -91,6 +104,7 @@ class _Span:
         self.t0 = 0.0
         self.child_us = 0.0
         self.parent: Optional["_Span"] = None
+        self.annotation = None
 
     def set(self, **kw) -> "_Span":
         self.args.update(kw)
@@ -108,11 +122,18 @@ class _Span:
         stack = self.tracer._stack()
         self.parent = stack[-1] if stack else None
         stack.append(self)
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self.annotation = jax.profiler.TraceAnnotation(self.name)
+            self.annotation.__enter__()
         self.t0 = _now_us()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = _now_us() - self.t0
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
+            self.annotation = None
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -128,9 +149,35 @@ class _Span:
         return False
 
 
+class _ScopeSpan:
+    """Span returned inside a jax trace: ``jax.named_scope(name)`` for
+    the body, so the operations traced there carry ``name`` in their
+    ``op_name``. No host time is taken; ``set`` and ``fence`` do
+    nothing."""
+    __slots__ = ("scope",)
+
+    def __init__(self, name: str):
+        import jax
+        self.scope = jax.named_scope(name)
+
+    def __enter__(self) -> "_ScopeSpan":
+        self.scope.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.scope.__exit__(*exc)
+        return False
+
+    def set(self, **_kw) -> "_ScopeSpan":
+        return self
+
+    def fence(self, value):
+        return value
+
+
 class _NullSpan:
-    """Inert span returned when no tracer is active (or the caller is
-    inside a jax trace). One shared instance; every method is a no-op."""
+    """Inert span returned outside a jax trace when no tracer is active.
+    One shared instance; every method is a no-op."""
     __slots__ = ()
 
     def __enter__(self) -> "_NullSpan":
@@ -178,17 +225,6 @@ class Tracer:
 
     def span(self, name: str, cat: str = "phase", **args) -> _Span:
         return _Span(self, name, cat, args)
-
-    def instant(self, name: str, cat: str = "mark", **args) -> None:
-        self._record({"name": name, "cat": cat, "ph": "i",
-                      "ts": _now_us(), "pid": self.pid,
-                      "tid": threading.get_ident() & 0xFFFF, "s": "t",
-                      "args": args})
-
-    def counter(self, name: str, **series: float) -> None:
-        self._record({"name": name, "cat": "metric", "ph": "C",
-                      "ts": _now_us(), "pid": self.pid, "tid": 0,
-                      "args": dict(series)})
 
     # -- views ---------------------------------------------------------------
     def spans(self, name: Optional[str] = None) -> List[Dict[str, Any]]:
@@ -269,16 +305,18 @@ def active() -> Optional[Tracer]:
 
 
 def phase(name: str, cat: str = "phase", **args):
-    """Span hook for instrumented library code (``repro.plan.exchange``
-    phases: plan_build / condense / dispatch / expert_ffn / combine).
+    """Span hook for instrumented library code: ``embed``, ``attention``,
+    ``router``, ``plan_build`` / ``condense``, ``exchange`` / ``dispatch``
+    / ``expert_ffn`` / ``combine``, ``lm_head``, ``optimizer``.
 
-    Returns :data:`NULL_SPAN` (free) unless a tracer is active AND the
-    caller runs outside a jax trace — so production steps pay one
-    module-global comparison, and jitted/scanned bodies never record
-    compile-time timestamps."""
+    Inside a jax trace it returns a ``jax.named_scope(name)`` span, so
+    the compiled step names its operations (jitted/scanned bodies never
+    record compile-time timestamps). Outside one it returns a host span
+    of the active tracer, or :data:`NULL_SPAN` (free) when none is
+    active."""
+    if not _trace_state_clean():
+        return _ScopeSpan(name)
     tracer = _ACTIVE
     if tracer is None:
-        return NULL_SPAN
-    if not _trace_state_clean():
         return NULL_SPAN
     return tracer.span(name, cat, **args)

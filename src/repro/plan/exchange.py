@@ -975,57 +975,59 @@ def execute_plan(params, x: Array, sideband: Dict[str, Array],
         # vanilla: rows returned to their source in dispatch layout —
         # replica copies merge in the same fixed per-copy k-order sum
         # as owner copies (the deterministic replica-merge order)
-        vals = back[r_safe, p_safe] * v_f[:, None].astype(cdt)  # [T*k, d]
-        delta = jnp.sum(vals.reshape(T, m.top_k, d), axis=1)
-        y_tok = xf + delta.astype(xf.dtype)
+        with obs_trace.phase("combine_unpack"):
+            vals = back[r_safe, p_safe] * v_f[:, None].astype(cdt)
+            delta = jnp.sum(vals.reshape(T, m.top_k, d), axis=1)
+            y_tok = xf + delta.astype(xf.dtype)
         c_drop = jnp.float32(0.0)
         local_frac = jnp.float32(1.0 / M)
         new_sideband = dict(sideband)
     else:
-        # regroup rows by destination device (priority: residual rows first)
-        R = n_lanes * M * C
-        o_f = out_rows.reshape(R, d)
-        dslot = rmeta[..., 0].reshape(R) - 1               # -1 = empty row
-        rpos = rmeta[..., 1].reshape(R)
-        rprim = prim.reshape(R) > 0.5
-        rvalid = dslot >= 0
-        ddev = jnp.where(rvalid, dslot // n_seq, M)        # M = dummy bin
-        prio = (~rvalid).astype(jnp.int32) * 2 + (~rprim).astype(jnp.int32)
-        order = jnp.argsort(prio, stable=True)
-        o_f, dslot, rpos, ddev, rvalid = (a[order] for a in
-                                          (o_f, dslot, rpos, ddev, rvalid))
-        C_comb = max(8, int(math.ceil(
-            plan.combine_slack * n_lanes * C / 8)) * 8)
-        oh = jax.nn.one_hot(ddev, M, dtype=jnp.int32)
-        rank = (jnp.cumsum(oh, axis=0) - oh)[jnp.arange(R), jnp.where(
-            rvalid, ddev, 0)]
-        keep_c = rvalid & (rank < C_comb)
-        n_rv = jnp.sum(rvalid.astype(jnp.float32))
-        c_drop = 1.0 - jnp.sum(keep_c.astype(jnp.float32)) / jnp.maximum(
-            n_rv, 1.0)
-        local_frac = jnp.sum((keep_c & (ddev == my)).astype(jnp.float32)) \
-            / jnp.maximum(n_rv, 1.0)
-        dd_s = jnp.where(keep_c, ddev, 0)
-        rk_s = jnp.where(keep_c, rank, 0)
-        cbuf = jnp.zeros((M, C_comb, d), cdt).at[dd_s, rk_s].add(
-            o_f * keep_c[:, None].astype(cdt), mode="drop")
-        cmeta = jnp.zeros((M, C_comb, 2), jnp.int32).at[dd_s, rk_s].add(
-            jnp.stack([jnp.where(keep_c, dslot % n_seq + 1, 0),
-                       jnp.where(keep_c, rpos, 0)], -1), mode="drop")
-        if M > 1:
-            cbuf = cwire.ship_rows(comm.combine, cbuf, d, plan.wire_dtype)
-            cmeta = comm.combine(cmeta)
-        rs = cbuf.reshape(M * C_comb, d)
-        rslot = cmeta[..., 0].reshape(-1) - 1
-        rp = cmeta[..., 1].reshape(-1)
-        ok = rslot >= 0
-        y_grid = jnp.zeros((n_seq, S, d), cdt).at[
-            jnp.where(ok, rslot, 0), jnp.where(ok, rp, 0)].add(
-            rs * ok[:, None].astype(cdt), mode="drop")
-        y_tok = y_grid.reshape(T, d).astype(xf.dtype)
-        # sideband travels with sequences
-        new_sideband = _exchange_sideband(
-            sideband, dest_global, n_seq, M, comm)
+        with obs_trace.phase("combine"):
+            # regroup rows by destination device (residual rows first)
+            R = n_lanes * M * C
+            o_f = out_rows.reshape(R, d)
+            dslot = rmeta[..., 0].reshape(R) - 1               # -1 = empty row
+            rpos = rmeta[..., 1].reshape(R)
+            rprim = prim.reshape(R) > 0.5
+            rvalid = dslot >= 0
+            ddev = jnp.where(rvalid, dslot // n_seq, M)        # M = dummy bin
+            prio = (~rvalid).astype(jnp.int32) * 2 + (~rprim).astype(jnp.int32)
+            order = jnp.argsort(prio, stable=True)
+            o_f, dslot, rpos, ddev, rvalid = (a[order] for a in
+                                              (o_f, dslot, rpos, ddev, rvalid))
+            C_comb = max(8, int(math.ceil(
+                plan.combine_slack * n_lanes * C / 8)) * 8)
+            oh = jax.nn.one_hot(ddev, M, dtype=jnp.int32)
+            rank = (jnp.cumsum(oh, axis=0) - oh)[jnp.arange(R), jnp.where(
+                rvalid, ddev, 0)]
+            keep_c = rvalid & (rank < C_comb)
+            n_rv = jnp.sum(rvalid.astype(jnp.float32))
+            c_drop = 1.0 - jnp.sum(keep_c.astype(jnp.float32)) / jnp.maximum(
+                n_rv, 1.0)
+            local_frac = jnp.sum((keep_c & (ddev == my)).astype(jnp.float32)) \
+                / jnp.maximum(n_rv, 1.0)
+            dd_s = jnp.where(keep_c, ddev, 0)
+            rk_s = jnp.where(keep_c, rank, 0)
+            cbuf = jnp.zeros((M, C_comb, d), cdt).at[dd_s, rk_s].add(
+                o_f * keep_c[:, None].astype(cdt), mode="drop")
+            cmeta = jnp.zeros((M, C_comb, 2), jnp.int32).at[dd_s, rk_s].add(
+                jnp.stack([jnp.where(keep_c, dslot % n_seq + 1, 0),
+                           jnp.where(keep_c, rpos, 0)], -1), mode="drop")
+            if M > 1:
+                cbuf = cwire.ship_rows(comm.combine, cbuf, d, plan.wire_dtype)
+                cmeta = comm.combine(cmeta)
+            rs = cbuf.reshape(M * C_comb, d)
+            rslot = cmeta[..., 0].reshape(-1) - 1
+            rp = cmeta[..., 1].reshape(-1)
+            ok = rslot >= 0
+            y_grid = jnp.zeros((n_seq, S, d), cdt).at[
+                jnp.where(ok, rslot, 0), jnp.where(ok, rp, 0)].add(
+                rs * ok[:, None].astype(cdt), mode="drop")
+            y_tok = y_grid.reshape(T, d).astype(xf.dtype)
+            # sideband travels with sequences
+            new_sideband = _exchange_sideband(
+                sideband, dest_global, n_seq, M, comm)
 
     return _finish(y_tok, new_sideband, s_next, c_drop, local_frac,
                    jnp.float32(0.0))

@@ -20,6 +20,7 @@ from repro.core import moe_layer
 from repro.core.condensation import adaptive_threshold
 from repro.dist import DistContext
 from repro.models import transformer as tf
+from repro.obs import trace as obs_trace
 
 
 class LuffyState(NamedTuple):
@@ -93,8 +94,9 @@ def make_train_step(cfg: ModelConfig, luffy: LuffyConfig,
         if param_pspecs is not None and dist.enabled:
             grads = jax.tree.map(
                 lambda g, sp: dist.constrain(g, sp), grads, param_pspecs)
-        params, opt_state, ometrics = optim.update(params, grads, opt_state,
-                                                   ocfg)
+        with obs_trace.phase("optimizer"):
+            params, opt_state, ometrics = optim.update(params, grads,
+                                                       opt_state, ocfg)
         metrics = dict(metrics)
         ef_next = metrics.pop("_wire_ef", None)
         metrics.update(ometrics)

@@ -60,3 +60,34 @@ def test_train_main_in_process(monkeypatch, tmp_path, capsys):
     assert run["expert_shard"] == [2, 4, 256, 512]
     out = capsys.readouterr().out
     assert "devices: platform=cpu" in out and "compile bucket=0" in out
+
+
+def test_train_trace_writes_the_profiler_trace_beside_its_json(
+        monkeypatch, tmp_path):
+    """--trace names the loop's spans as the benchmark does (upload, step,
+    metrics, bucket) and writes the JAX profiler's trace beside the
+    Chrome JSON, with those spans on the profiler's host plane."""
+    import glob
+    import json
+    from jax.profiler import ProfileData
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    from repro.launch import train
+    out = tmp_path / "trace.json"
+    try:
+        train.main(["--arch", "moe-gpt2", "--reduced", "--steps", "2",
+                    "--seq-len", "64", "--global-batch", "2",
+                    "--mesh", "none", "--trace-out", str(out)])
+        # the cache may not hand the step another program's op_names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+    names = {e["name"] for e in json.loads(out.read_text())["traceEvents"]}
+    loop = {"upload", "step", "metrics", "bucket"}
+    assert loop <= names, names
+    (xplane,) = glob.glob(str(tmp_path / "trace.profile" / "**" /
+                              "*.xplane.pb"), recursive=True)
+    host = {ev.name for plane in ProfileData.from_file(xplane).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+    assert loop <= host, loop - host

@@ -1,7 +1,8 @@
 """repro.obs (DESIGN.md §11): span nesting + exclusive-time invariants
-(property test), Chrome-trace export validity, the phase() hook's no-op
-guarantees (no tracer / inside a jax trace), the unified metrics
-registry (canonical names, counter accumulation, applicability
+(property test), Chrome-trace export validity, the phase() hook (no-op
+without a tracer, a profiler annotation with one, a named scope inside a
+jax trace that names every layer of the compiled train step), the unified
+metrics registry (canonical names, counter accumulation, applicability
 masking — the inter_bytes_shipped null fix), calibration artifact
 round-trip + stale-fingerprint/version-drift miss semantics, the
 plan_key chunk-overhead extension's backward compatibility, and the
@@ -106,8 +107,6 @@ def test_chrome_trace_export(tmp_path):
     tr = Tracer()
     with tr.span("step", cat="step", step=0):
         pass
-    tr.instant("mark")
-    tr.counter("tokens", condensed=3.0)
     path = tmp_path / "sub" / "trace.json"
     tr.write(path)
     doc = json.loads(path.read_text())
@@ -169,22 +168,126 @@ def test_phase_hook_noop_without_tracer():
 
 def test_phase_hook_noop_inside_jax_trace():
     """Inside a scan/jit body host timestamps are compile-time garbage:
-    phase() must drop the span, not record it."""
+    phase() records no host span there, and names the traced operations
+    with a named scope instead."""
     import jax
     import jax.numpy as jnp
     tr = obs_trace.activate(Tracer())
     try:
         def body(c, x):
-            with obs_trace.phase("inner"):
-                c = c + x
+            with obs_trace.phase("inner") as sp:
+                c = sp.fence(c + x)
             return c, c
         jax.lax.scan(body, jnp.float32(0.0), jnp.arange(4, dtype=jnp.float32))
-        jax.jit(lambda x: obs_trace.phase("jitted").__enter__() and x)(
-            jnp.float32(1.0))
+
+        def jitted(x):
+            with obs_trace.phase("jitted"):
+                return jnp.sin(x)
+        lowered = jax.jit(jitted).lower(jnp.float32(1.0))
     finally:
         obs_trace.deactivate()
     assert tr.spans("inner") == []
     assert tr.spans("jitted") == []
+    assert 'op_name="jit(jitted)/jitted/sin"' in \
+        lowered.compiler_ir("hlo").as_hlo_module().to_string()
+
+
+def _profiled_host_events(log_dir):
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return {ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_phase_records_a_host_span_and_annotation_only_with_a_tracer(
+        tmp_path):
+    """Outside a jax trace, phase() is NULL_SPAN without a tracer; with
+    one it records a host span and a profiler TraceAnnotation of the same
+    name, which puts the span on the device trace's clock."""
+    import jax
+    obs_trace.deactivate()
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs_trace.phase("untraced_phase") is NULL_SPAN
+        with obs_trace.phase("untraced_phase"):
+            pass
+        tr = obs_trace.activate(Tracer())
+        try:
+            with obs_trace.phase("traced_phase"):
+                pass
+        finally:
+            obs_trace.deactivate()
+    assert [e["name"] for e in tr.spans()] == ["traced_phase"]
+    names = _profiled_host_events(tmp_path)
+    assert "traced_phase" in names
+    assert "untraced_phase" not in names
+
+
+SCOPES_FWD_ONLY = ("plan_build", "condense", "optimizer")
+SCOPES_BOTH = ("router", "dispatch", "expert_ffn", "combine", "attention",
+               "embed", "lm_head")
+
+
+def test_train_step_names_every_layer_scope():
+    """The compiled train step of a tiny expert-parallel moe-gpt2 (4 host
+    devices, migration and condensation on) carries each layer's scope in
+    its instructions' op_name, in the forward pass and, where a gradient
+    flows, in the backward pass (transpose(jvp(...))). The plan's
+    decisions have no gradient and the optimizer runs on gradients."""
+    script = textwrap.dedent("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        import json, re
+        import jax
+        from repro import optim, train_lib
+        from repro.comm import make_mesh
+        from repro.config import (LuffyConfig, OptimConfig, ShapeConfig,
+                                  reduced)
+        from repro.configs import get_config
+        from repro.dist import make_dist
+        from repro.launch.mesh import topology_for_mesh
+        from repro.models.model import build_model
+
+        cfg = reduced(get_config("moe-gpt2"), num_layers=2, d_model=64,
+                      max_experts=4, seq_len_hint=64)
+        shape = ShapeConfig("train", 64, 4, "train")
+        mesh = make_mesh((1, 4), ("data", "model"))
+        dist = make_dist(mesh, "train", 4, moe_arch=True,
+                         topology=topology_for_mesh(mesh))
+        luffy = LuffyConfig(enable_condensation=True, enable_migration=True,
+                            condense_group=32, combine_slack=2.0)
+        ocfg = OptimConfig()
+        model = build_model(cfg)
+        struct = model.init_struct()
+        cap = train_lib.capacity_for_bucket(cfg, shape, dist, luffy, 0)
+        step = train_lib.make_train_step(
+            cfg, luffy, ocfg, dist, cap,
+            param_pspecs=model.param_pspecs(dist, struct))
+        opt = jax.eval_shape(lambda p: optim.init_opt_state(p, ocfg), struct)
+        lst = jax.eval_shape(train_lib.init_luffy_state)
+        batch = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                 for k, v in model.input_specs(shape, dist).items()}
+        text = jax.jit(step).lower(struct, opt, lst, batch).compile().as_text()
+        found = {}
+        for op in re.findall(r'op_name="([^"]*)"', text):
+            for w in set(re.findall(r"\\w+", op)):
+                found.setdefault(w, set()).add("bwd" if "transpose(" in op
+                                               else "fwd")
+        print(json.dumps({k: sorted(v) for k, v in found.items()}))
+    """)
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    out = subprocess.run([sys.executable, "-c", script], cwd=ROOT,
+                         capture_output=True, text=True, env=env,
+                         timeout=1200)
+    assert out.returncode == 0, out.stderr[-3000:]
+    found = json.loads(out.stdout.strip().splitlines()[-1])
+    for scope in SCOPES_BOTH:
+        assert found.get(scope) == ["bwd", "fwd"], (scope, found.get(scope))
+    for scope in SCOPES_FWD_ONLY:
+        assert found.get(scope) == ["fwd"], (scope, found.get(scope))
 
 
 def test_tracer_summary():
