@@ -8,11 +8,13 @@ steps — one compiled executable per bucket, cached (DESIGN.md §3).
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import optim
 from repro.config import LuffyConfig, ModelConfig, OptimConfig, ShapeConfig
@@ -40,6 +42,53 @@ def init_luffy_state(wire_ef_shape: Optional[Tuple[int, ...]] = None
           if wire_ef_shape is not None else None)
     return LuffyState(jnp.float32(-1.0), jnp.float32(-1.0), jnp.int32(0),
                       ef)
+
+
+# device-to-host pulls made by finalize_metrics: one per StepMetrics, one
+# per device value of a plain dict
+PULLS = 0
+
+
+@jax.tree_util.register_pytree_node_class
+class StepMetrics(Mapping):
+    """A train step's scalar metrics, packed on the device into one f32
+    vector so that the host pulls them in one transfer (DESIGN.md §11).
+    The names, in the step's order, are static pytree aux data. As a
+    ``Mapping`` it reads like the step's old dict: ``m["loss"]`` is the
+    device slice."""
+
+    def __init__(self, names: Tuple[str, ...], values):
+        self.names = names
+        self.values = values
+        self._index = {k: i for i, k in enumerate(names)}
+
+    @classmethod
+    def pack(cls, metrics: Dict[str, Any]) -> "StepMetrics":
+        values = []
+        for k, v in metrics.items():
+            v = jnp.asarray(v)
+            # only an f32 scalar round-trips bit for bit as its f32 slot
+            if v.shape != () or v.dtype != jnp.float32:
+                raise ValueError(f"metric {k!r} is not an f32 scalar: "
+                                 f"{v.dtype}{list(v.shape)}")
+            values.append(v)
+        return cls(tuple(metrics), jnp.stack(values))
+
+    def tree_flatten(self):
+        return (self.values,), self.names
+
+    @classmethod
+    def tree_unflatten(cls, names, leaves):
+        return cls(names, *leaves)
+
+    def __getitem__(self, key):
+        return self.values[self._index[key]]
+
+    def __iter__(self):
+        return iter(self.names)
+
+    def __len__(self):
+        return len(self.names)
 
 
 def tokens_per_device(cfg: ModelConfig, shape: ShapeConfig,
@@ -76,8 +125,8 @@ def make_train_step(cfg: ModelConfig, luffy: LuffyConfig,
                     ocfg: OptimConfig, dist: DistContext, capacity: int,
                     param_pspecs=None):
     """Returns step(params, opt_state, lstate, batch) ->
-    (params, opt_state, lstate, metrics). Not yet jitted (callers attach
-    shardings / donation).
+    (params, opt_state, lstate, metrics), metrics a ``StepMetrics``.
+    Not yet jitted (callers attach shardings / donation).
 
     param_pspecs: if given, gradients are sharding-constrained back to the
     parameter layout right after value_and_grad — without this, grads of
@@ -106,7 +155,7 @@ def make_train_step(cfg: ModelConfig, luffy: LuffyConfig,
             jnp.where(lstate.l_ini > 0, lstate.l_ini, new_l),
             new_l, lstate.step + 1,
             ef_next if ef_next is not None else lstate.wire_ef)
-        return params, opt_state, lstate2, metrics
+        return params, opt_state, lstate2, StepMetrics.pack(metrics)
 
     return step
 
@@ -125,17 +174,24 @@ def make_eval_step(cfg: ModelConfig, luffy: LuffyConfig, dist: DistContext,
 
 
 def finalize_metrics(metrics, luffy: LuffyConfig):
-    """Host-side view of one step's metrics dict: device scalars pulled
-    to python floats, config-inapplicable keys masked to ``None`` (an
+    """Host-side view of one step's metrics: device scalars pulled to
+    python floats (a ``StepMetrics`` in one transfer, a plain dict value
+    by value), config-inapplicable keys masked to ``None`` (an
     ``inter_bytes_shipped`` of 0.0 from a dense-wire run means "nothing
     measured", not "zero bytes"; see ``repro.obs.metrics``)."""
     from repro.obs import metrics as obs_metrics
-    out = {}
-    for k, v in metrics.items():
-        try:
-            out[k] = float(v)
-        except (TypeError, ValueError):
-            out[k] = v
+    global PULLS
+    if isinstance(metrics, StepMetrics):
+        PULLS += 1
+        out = dict(zip(metrics.names, np.asarray(metrics.values).tolist()))
+    else:
+        out = {}
+        for k, v in metrics.items():
+            PULLS += isinstance(v, jax.Array)
+            try:
+                out[k] = float(v)
+            except (TypeError, ValueError):
+                out[k] = v
     return obs_metrics.mask_inapplicable(out, luffy)
 
 

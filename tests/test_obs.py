@@ -3,10 +3,11 @@
 without a tracer, a profiler annotation with one, a named scope inside a
 jax trace that names every layer of the compiled train step), the unified
 metrics registry (canonical names, counter accumulation, applicability
-masking — the inter_bytes_shipped null fix), calibration artifact
-round-trip + stale-fingerprint/version-drift miss semantics, the
-plan_key chunk-overhead extension's backward compatibility, and the
-8-device traced-exchange invariant (subprocess)."""
+masking — the inter_bytes_shipped null fix), the train step's packed
+metrics (one device-to-host pull, bit for bit the per-key values),
+calibration artifact round-trip + stale-fingerprint/version-drift miss
+semantics, the plan_key chunk-overhead extension's backward
+compatibility, and the 8-device traced-exchange invariant (subprocess)."""
 import json
 import os
 import subprocess
@@ -496,6 +497,110 @@ def test_finalize_metrics_masks_and_floats():
     assert m["loss"] == 1.5 and isinstance(m["loss"], float)
     assert m["inter_bytes_shipped"] is None
     assert m["bucket"] == 1.0
+
+
+# the step's 20 scalar metrics: tf.forward_train's 17, optim.update's two,
+# and the total loss
+STEP_METRIC_NAMES = {
+    "loss", "aux_loss", "dispatch_drop", "combine_drop", "condense_rate",
+    "local_frac", "traffic_before", "traffic_after", "inter_bytes_flat",
+    "inter_bytes_dedup", "inter_bytes_shipped", "plans_built",
+    "plans_reused", "plan_reuse_mismatch", "measured_pairs",
+    "condense_built", "condense_reused", "grad_norm", "lr", "total_loss"}
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["condense", "no_condense"])
+def tiny_step(request):
+    """One CPU train step of a tiny MoE model under a hier / dense-wire
+    config: (jitted step, its inputs, its outputs, the config)."""
+    import jax
+    import jax.numpy as jnp
+    from repro import optim, train_lib
+    from repro.config import OptimConfig, ShapeConfig, reduced
+    from repro.configs import get_config
+    from repro.core.moe_layer import capacity_for
+    from repro.data import SyntheticLM
+    from repro.dist import single_device
+    from repro.models.model import build_model
+    cfg = reduced(get_config("moe-gpt2"), num_layers=2)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    luffy = LuffyConfig(enable_condensation=request.param,
+                        condense_group=64, comm_mode="hier")
+    ocfg = OptimConfig()
+    cap = capacity_for(cfg.moe, 4 * 128, cfg.moe.num_experts)
+    step = jax.jit(train_lib.make_train_step(cfg, luffy, ocfg,
+                                             single_device(), cap))
+    batch = {k: jnp.asarray(v) for k, v in
+             SyntheticLM(cfg, ShapeConfig("t", 128, 4, "train"))
+             .batch(0).items()}
+    args = (params, optim.init_opt_state(params, ocfg),
+            train_lib.init_luffy_state(), batch)
+    return step, args, step(*args), luffy
+
+
+def test_finalize_metrics_one_pull_equals_per_key_pull(tiny_step):
+    import struct
+    from repro import train_lib
+    _, _, out, luffy = tiny_step
+    m = out[3]
+    base = train_lib.PULLS
+    packed = train_lib.finalize_metrics(m, luffy)
+    assert train_lib.PULLS - base == 1
+    base = train_lib.PULLS
+    per_key = train_lib.finalize_metrics(dict(m), luffy)
+    assert train_lib.PULLS - base == len(STEP_METRIC_NAMES)
+
+    def bits(d):
+        return [(k, None if v is None else struct.pack("<d", v))
+                for k, v in d.items()]
+
+    assert bits(packed) == bits(per_key)
+    assert all(isinstance(v, float) for k, v in packed.items()
+               if k != "inter_bytes_shipped")
+    # hier comm over the dense wire: nothing measured, not 0 bytes
+    assert packed["inter_bytes_shipped"] is None
+    assert (packed["condense_rate"] > 0.0) == luffy.enable_condensation
+
+
+def test_step_metrics_reads_like_the_old_dict(tiny_step):
+    import jax
+    import jax.numpy as jnp
+    from repro import train_lib
+    step, args, out, _ = tiny_step
+    m = out[3]
+    assert isinstance(m, train_lib.StepMetrics)
+    assert set(m.keys()) == STEP_METRIC_NAMES and len(m) == 20
+    assert "_wire_ef" not in m
+    loss = m["loss"]
+    assert isinstance(loss, jax.Array) and loss.shape == ()
+    assert float(loss) == float(m.values[list(m).index("loss")])
+    assert set(dict(m)) == STEP_METRIC_NAMES
+    # one f32 leaf, names in the static aux data
+    leaves, treedef = jax.tree.flatten(m)
+    assert len(leaves) == 1 and leaves[0].shape == (20,)
+    assert leaves[0].dtype == jnp.float32
+    again = jax.tree.unflatten(treedef, leaves)
+    assert list(again) == list(m) and again.names == m.names
+    assert jax.block_until_ready(m) is not None
+    assert list(jax.jit(lambda x: x)(m)) == list(m)
+
+    def passthrough(p, o, lstate, batch):   # as the harness's fault wrapper
+        res = step(p, o, lstate, batch)
+        return res[0], res[1], res[2], res[3]
+
+    m2 = jax.block_until_ready(passthrough(*args))[3]
+    assert isinstance(m2, train_lib.StepMetrics)
+    assert float(m2["loss"]) == float(m["loss"])
+
+
+def test_step_metrics_packs_only_f32_scalars():
+    import jax.numpy as jnp
+    from repro import train_lib
+    # an int count above 2**24 would round through an f32 slot
+    for bad in (jnp.int32(2 ** 24 + 1), jnp.zeros(3), jnp.bfloat16(1.0)):
+        with pytest.raises(ValueError, match="not an f32 scalar"):
+            train_lib.StepMetrics.pack({"loss": jnp.float32(1.0), "x": bad})
 
 
 # ---------------------------------------------------------------------------
