@@ -7,8 +7,11 @@ rehearsal (``rehearse.py``) share.
 Everything particular to a configuration, a traffic mix or a per-layer
 metric lives in a file of its own, found by the name that
 ``BENCHMARK.json`` gives: ``configs/<config>.json`` (sizes, with the
-reference module it names under ``reference/``), ``traffic/<traffic>.json``,
-``metrics/<metric>.py`` and ``limits/<workload>.json``.
+architecture it names under ``reference``), ``traffic/<traffic>.json``,
+``metrics/<metric>.py`` and ``limits/<workload>.json``. An architecture
+is two modules of that name: ``reference/<name>.py``, its plain
+reference, and ``layout/<name>.py``, what the harness needs to know of
+it (``layout_module``).
 """
 from __future__ import annotations
 
@@ -39,11 +42,6 @@ JOB_OPT = {"lr": 1e-3, "warmup_steps": 100, "total_steps": 10_000,
            "grad_clip": 1.0}
 CHECK_STEPS = 3          # steps the reference follows
 TRACE_CAP_S = 8.0        # longest traced window (traces are large)
-# config-file key -> how the program's ModelConfig holds it
-SIZE_KEYS = ("num_layers", "d_model", "num_heads", "head_dim", "vocab_size",
-             "num_experts", "top_k", "expert_d_ff", "capacity_factor",
-             "router_aux_coef", "use_rope", "tie_embeddings", "norm", "act",
-             "param_dtype", "compute_dtype")
 NUMBERS = ("loss_step1", "loss_step2", "loss_step3", "grad1",
            "grad1_median", "change")
 NOT_FINITE = 1e30        # what a number that is not finite reads as
@@ -112,49 +110,34 @@ def reference_module(conf: dict) -> ModuleType:
     return load_module(ROOT / "reference" / f"{conf['reference']}.py")
 
 
+def layout_module(conf: dict) -> ModuleType:
+    """The architecture's layout: ``SIZE_KEYS``, ``program_sizes(base)``
+    and ``program_config(base, conf)``; ``shapes(conf)`` and
+    ``PROGRAM_PATHS`` (``weights.py``); ``forward_flops_per_token``
+    (``flops.py``); optionally ``SCOPE_CLASSES``, scope classes matched
+    before ``scopes.CLASSES``."""
+    return load_module(ROOT / "layout" / f"{conf['reference']}.py")
+
+
 # ---------------------------------------------------------------------------
 # the program under test
 # ---------------------------------------------------------------------------
 
 def program_config(conf: dict):
     """The program's ModelConfig for this configuration file: the
-    registry's architecture with the file's sizes. A size that differs
-    from the registry and is not listed in ``reduced`` is an error."""
+    registry's architecture with the file's sizes, as the layout places
+    them. A size that differs from the registry and is not listed in
+    ``reduced`` is an error."""
     from repro.configs import get_config
+    layout = layout_module(conf)
     base = get_config(conf["arch"])
-    now = {"num_layers": base.num_layers, "d_model": base.d_model,
-           "num_heads": base.attn.num_heads, "head_dim": base.attn.head_dim,
-           "vocab_size": base.vocab_size,
-           "num_experts": base.moe.num_experts, "top_k": base.moe.top_k,
-           "expert_d_ff": base.moe.d_ff,
-           "capacity_factor": base.moe.capacity_factor,
-           "router_aux_coef": base.moe.router_aux_coef,
-           "use_rope": base.attn.use_rope,
-           "tie_embeddings": base.tie_embeddings, "norm": base.norm,
-           "act": base.act, "param_dtype": base.param_dtype,
-           "compute_dtype": base.compute_dtype}
-    if not conf["gated_experts"]:
-        raise ValueError("the program builds every expert gated; a "
-                         "configuration of ungated experts cannot run")
-    changed = sorted(k for k in SIZE_KEYS if conf[k] != now[k])
+    now = layout.program_sizes(base)
+    changed = sorted(k for k in layout.SIZE_KEYS if conf[k] != now[k])
     unlisted = [k for k in changed if k not in conf["reduced"]]
     if unlisted:
         raise ValueError(f"{conf['arch']}: {unlisted} differ from the "
                          f"program's configuration and are not in reduced")
-    attn = dataclasses.replace(
-        base.attn, num_heads=conf["num_heads"],
-        num_kv_heads=conf["num_heads"], head_dim=conf["head_dim"],
-        use_rope=conf["use_rope"])
-    moe = dataclasses.replace(
-        base.moe, num_experts=conf["num_experts"], top_k=conf["top_k"],
-        d_ff=conf["expert_d_ff"], capacity_factor=conf["capacity_factor"],
-        router_aux_coef=conf["router_aux_coef"])
-    return dataclasses.replace(
-        base, num_layers=conf["num_layers"], d_model=conf["d_model"],
-        vocab_size=conf["vocab_size"], attn=attn, moe=moe,
-        tie_embeddings=conf["tie_embeddings"], norm=conf["norm"],
-        act=conf["act"], param_dtype=conf["param_dtype"],
-        compute_dtype=conf["compute_dtype"])
+    return layout.program_config(base, conf)
 
 
 class Program:
@@ -175,6 +158,7 @@ class Program:
         self.cell = cell
         t = cell.traffic
         self.S, self.B = t["seq_len"], t["global_batch"]
+        self.layout = layout_module(cell.conf)
         self.cfg = program_config(cell.conf)
         self.shape = ShapeConfig("train", self.S, self.B, "train")
         self.devices = list(devices)[:cell.chips]
@@ -199,7 +183,7 @@ class Program:
             total_steps=JOB_OPT["total_steps"])
         self.model = build_model(self.cfg)
         self.struct = self.model.init_struct()
-        weights.check_layout(cell.conf, self.struct)
+        weights.check_layout(self.layout, cell.conf, self.struct)
         self.pspecs = self.model.param_pspecs(self.dist, self.struct)
         if self.dist.enabled:
             self.param_sh = jax.tree.map(self.dist.sharding, self.pspecs)
@@ -220,10 +204,11 @@ class Program:
         in one jitted call, weights from ``seed``."""
         import jax
         from repro import optim, train_lib
-        conf = self.cell.conf
+        conf, layout = self.cell.conf, self.layout
         key = weights.seed_key(seed)
         self.params = jax.jit(
-            lambda k: weights.to_program(weights.canonical(conf, k)),
+            lambda k: weights.to_program(
+                layout, weights.canonical(layout, conf, k)),
             out_shardings=self.param_sh)(key)
         opt_sh = optim.OptState(self.repl, self.param_sh, self.param_sh)
         self.opt = jax.jit(lambda p: optim.init_opt_state(p, self.ocfg),
@@ -313,18 +298,19 @@ class Program:
         import jax
         b1 = JOB_OPT["b1"]
         f = jax.jit(lambda mu: ref_mod.leaf_norms(
-            {n: a / (1.0 - b1) for n, a in weights.from_program(mu).items()}))
+            {n: a / (1.0 - b1)
+             for n, a in weights.from_program(self.layout, mu).items()}))
         return {n: float(v) for n, v in f(self.opt.mu).items()}
 
     def change_norms(self, ref_mod) -> Dict[str, float]:
         """Per-leaf norms of the parameters' change since the seed's
         weights (regenerated from the seed, not kept)."""
         import jax
-        conf = self.cell.conf
+        conf, layout = self.cell.conf, self.layout
 
         def f(p, key):
-            p0 = weights.canonical(conf, key)
-            now = weights.from_program(p)
+            p0 = weights.canonical(layout, conf, key)
+            now = weights.from_program(layout, p)
             return ref_mod.leaf_norms({n: now[n] - p0[n] for n in p0})
 
         return {n: float(v) for n, v in jax.jit(f)(self.params,
@@ -360,15 +346,16 @@ def reference_side(cell: Cell, seed: int, pool, *, rate=0.0, lower=False,
     ``lower=True``, or a planted fault), on one device."""
     import jax
     ref = reference_module(cell.conf)
+    layout = layout_module(cell.conf)
     conf = cell.conf
     key = weights.seed_key(seed)
-    p0 = jax.jit(lambda k: weights.canonical(conf, k))(key)
+    p0 = jax.jit(lambda k: weights.canonical(layout, conf, k))(key)
     S = cell.traffic["seq_len"]
     res = ref.train3(conf, p0, pool[:CHECK_STEPS], JOB_OPT, lower=lower,
                      group=min(128, S), rate=rate, fault=fault)
 
     def f(p, k):
-        p0 = weights.canonical(conf, k)
+        p0 = weights.canonical(layout, conf, k)
         return ref.leaf_norms({n: p[n] - p0[n] for n in p0})
 
     change = {n: float(v) for n, v in jax.jit(f)(res.pop("params"),
@@ -529,6 +516,7 @@ def run_cell(prog: Program, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
     import flops
+    import scopes
     import tracereduce
 
     cell = prog.cell
@@ -579,12 +567,17 @@ def run_cell(prog: Program, seed: int, seconds: float, trace: bool,
                for s in stats)
     dev = prog.devices[0]
     fpt = flops.train_flops_per_token(
-        prog.struct, cell.conf, traffic_mod.lengths(cell.traffic))
+        prog.layout, prog.struct, cell.conf,
+        traffic_mod.lengths(cell.traffic))
 
     reduced = None
     if trace:
         recs = tracereduce.load(trace_dir)
-        reduced = tracereduce.reduce(recs, tracereduce.window_of(recs))
+        win = tracereduce.window_of(recs)
+        reduced = tracereduce.reduce(recs, win)
+        reduced["scopes"] = scopes.of_run(
+            recs, win, prog.exes, steps,
+            getattr(prog.layout, "SCOPE_CLASSES", ()) + scopes.CLASSES)
     drops = [d for g in got.values() for d in g["drops"]] + [
         max(s["dispatch_drop"], s["combine_drop"] or 0.0) for s in steps]
     memory = {"allocator": stats[0], "executables": {
@@ -628,7 +621,10 @@ def run_cell(prog: Program, seed: int, seconds: float, trace: bool,
                     "reference_losses": {b: r["losses"]
                                          for b, r in refs.items()},
                     "buckets": sorted({s["bucket"] for s in steps}),
-                    "timeline": timeline(steps), "memory": memory}
+                    "timeline": timeline(steps), "memory": memory,
+                    "flops_per_token": fpt}
+    if trace:
+        out["_info"]["scopes"] = reduced["scopes"]
     return out
 
 

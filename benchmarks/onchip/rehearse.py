@@ -52,7 +52,6 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import harness
-    import weights
     from repro import optim, train_lib
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -90,8 +89,8 @@ def main(argv=None) -> int:
         return 0
     ref = harness.reference_module(cell.conf)
     one = SingleDeviceSharding(topo.devices[0])
-    canon = {n: sds(s, jnp.float32, one)
-             for n, (s, _, _) in weights.shapes(cell.conf).items()}
+    canon = {n: sds(s, jnp.float32, one) for n, (s, _, _)
+             in harness.layout_module(cell.conf).shapes(cell.conf).items()}
     vg, update = ref.step_fns(cell.conf, harness.JOB_OPT, group=min(128, S))
     scal = sds((), jnp.float32, one)
     t0 = time.perf_counter()

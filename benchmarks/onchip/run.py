@@ -9,15 +9,17 @@ bucket and runs three check steps through each bucket's step that the
 cell's condensation reaches (set-up), then trains for ``--seconds``
 through the launcher's host loop. With ``--trace 1`` the window is
 traced (at most 8 s) and the per-layer metrics are reported instead of
-the end-to-end ones. Afterwards the plain float32 reference follows each
+the end-to-end ones, with device ms a step per scope class on a
+``scopes {...}`` line. Afterwards the plain float32 reference follows each
 bucket's three check steps from the same weights and batches;
 ``correct`` says whether the program stayed within each limit of
 ``limits/<workload>.json``.
 
-Prints, on standard output, lines with the set-up time and the number
-of compiles in the window, the losses of the check steps, the window in
-quarters (steps, step time, rate buckets, condensation rate) and the
-memory readings, then one JSON result line; on standard error, last,
+Prints, on standard output, lines with the set-up time, the number of
+compiles in the window and the model FLOPs a token, the losses of the
+check steps, the window in quarters (steps, step time, rate buckets,
+condensation rate), the memory readings and, traced, the scope classes,
+then one JSON result line; on standard error, last,
 each number compared beside its limit. Exits non-zero, with no result,
 where JAX finds no TPU or fewer chips than the cell asks for.
 """
@@ -65,11 +67,14 @@ def report(out: dict) -> None:
     print(f"setup_s={info['setup_s']} compiles_in_window="
           f"{info['compiles_in_window']} steps={info['steps']} "
           f"window_s={info['window_s']} buckets={info['buckets']} "
-          f"max_drop={info['max_drop']}", flush=True)
+          f"max_drop={info['max_drop']} "
+          f"flops_per_token={info['flops_per_token']}", flush=True)
     print(f"losses program={info['program_losses']} "
           f"reference={info['reference_losses']}", flush=True)
     print(f"timeline {json.dumps(info['timeline'])}", flush=True)
     print(f"memory {json.dumps(info['memory'])}", flush=True)
+    if "scopes" in info:
+        print(f"scopes {json.dumps(info['scopes'])}", flush=True)
     for name, c in out["checks"].items():
         print(f"check {name} {c['value']} limit {c['limit']}",
               file=sys.stderr, flush=True)
@@ -94,6 +99,13 @@ def main(argv=None) -> int:
               f"{devs[0].platform} device(s)", file=sys.stderr)
         return 2
     setup_jax_cache()
+    if args.trace:
+        # the scope classes are read from the executables' op_name
+        # metadata; a cache key without it could hand this program an
+        # executable compiled from the same code under other scopes, or
+        # none
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     counter = harness.CompileCounter()
     prog = harness.Program(cell, devs)
     trace_dir = Path(tempfile.mkdtemp(prefix="onchip-trace-")) \

@@ -8,9 +8,12 @@ Each leaf device operation of the traced window (as
 ``tracereduce.load`` returns them) is looked up by its HLO instruction
 name in the ``op_name`` map of the executable that ran it: that of the
 rate bucket of the host ``step`` span the operation falls in. Its
-``op_name`` path gives its class, the first of ``CLASSES`` that names one
-of its scopes; an operation that is not found, or whose path names none,
-is ``other``.
+``op_name`` path gives its class, the first of the classes (``CLASSES``,
+after those an architecture's layout adds, ``SCOPE_CLASSES``) that names
+one of its scopes; an operation that is not found, or whose path names
+none, is ``other``. ``harness.run_cell`` puts the result of a traced
+run under ``"scopes"`` in the reduced trace, where the readers
+``metrics/<class>_ms.py`` find it (``class_ms``).
 
 ``attribute`` works on those records and maps alone, so it is tested on
 a hand-made trace and on a recorded chip trace.
@@ -32,10 +35,11 @@ import re
 import sys
 import time
 from collections import defaultdict
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 # class -> the scopes (``phase`` names) it holds; first match wins
-CLASSES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
+Classes = Tuple[Tuple[str, Tuple[str, ...]], ...]
+CLASSES: Classes = (
     ("optimizer", ("optimizer",)),
     ("expert_ffn", ("expert_ffn",)),
     ("dispatch_combine", ("dispatch", "dispatch_pack", "combine",
@@ -72,18 +76,19 @@ def words(op_name: str) -> set:
     return set(re.findall(r"\w+", _INDEX.sub("", op_name)))
 
 
-def classify(op_name: str) -> str:
+def classify(op_name: str, classes: Classes = CLASSES) -> str:
     w = words(op_name)
-    for cls, scopes in CLASSES:
+    for cls, scopes in classes:
         if w.intersection(scopes):
             return cls
     return OTHER
 
 
-def scopes_seen(maps: Dict[int, Dict[str, str]]) -> List[str]:
-    """The scopes of ``CLASSES`` that any instruction of the maps is
+def scopes_seen(maps: Dict[int, Dict[str, str]],
+                classes: Classes = CLASSES) -> List[str]:
+    """The scopes of ``classes`` that any instruction of the maps is
     under: a program without named scopes has none."""
-    every = {s for _, ss in CLASSES for s in ss}
+    every = {s for _, ss in classes for s in ss}
     seen = set()
     for m in maps.values():
         for op in set(m.values()):
@@ -92,11 +97,13 @@ def scopes_seen(maps: Dict[int, Dict[str, str]]) -> List[str]:
 
 
 def attribute(rec: dict, window: Tuple[int, int],
-              maps: Dict[int, Dict[str, str]], buckets: List[int]) -> dict:
+              maps: Dict[int, Dict[str, str]], buckets: List[int],
+              classes: Classes = CLASSES) -> dict:
     """Device time per class in ``window``, per step and mean over the
     chips. ``rec`` is ``tracereduce.load``'s records, ``maps`` each rate
     bucket's ``op_names``, ``buckets`` the bucket of each step of the
-    window in order (the i-th host ``step`` span ran ``buckets[i]``).
+    window in order (the i-th host ``step`` span ran ``buckets[i]``),
+    ``classes`` the classes in the order they are matched.
 
     Returns ms per step per class, the leaf operations' summed time per
     step (the classes add up to it), the share of it whose operation was
@@ -126,33 +133,49 @@ def attribute(rec: dict, window: Tuple[int, int],
                 op = maps.get(buckets[i], {}).get(name)
             if op is not None:
                 found += t
-            cls = OTHER if op is None else classify(op)
+            cls = OTHER if op is None else classify(op, classes)
             t_cls[cls] += t
             t_op[(cls, label, op or "")] += t
     n = max(len(spans), 1)
     per_step = 1e3 / n_dev / n
-    top = {c: [] for c in NAMES}
+    names = tuple(dict.fromkeys(c for c, _ in classes)) + (OTHER,)
+    top = {c: [] for c in names}
     for (cls, label, op), t in sorted(t_op.items(), key=lambda kv: -kv[1]):
         if len(top[cls]) < TOP:
             top[cls].append([label, op, t * per_step])
     return {"source": "hlo_op_name", "steps": len(spans),
-            "ms": {c: t_cls[c] * per_step for c in NAMES},
+            "ms": {c: t_cls[c] * per_step for c in names},
             "leaf_ms": total * per_step,
             "found_share": 100.0 * found / total if total else 0.0,
-            "scopes_seen": scopes_seen(maps), "top": top}
+            "scopes_seen": scopes_seen(maps, classes), "top": top}
 
 
 def of_run(rec: dict, window: Tuple[int, int], exes: dict,
-           steps: List[dict]) -> dict:
+           steps: List[dict], classes: Classes = CLASSES) -> dict:
     """``attribute`` for a traced run: the maps from the compiled
     executables of the buckets the window ran (``exe.as_text()``). Adds
     the seconds this took."""
     t0 = time.perf_counter()
     buckets = [s["bucket"] for s in steps]
     maps = {b: op_names(exes[b].as_text()) for b in sorted(set(buckets))}
-    out = attribute(rec, window, maps, buckets)
+    out = attribute(rec, window, maps, buckets, classes)
     out["seconds"] = time.perf_counter() - t0
     return out
+
+
+def class_ms(rec, cls: str, classes: Classes = CLASSES) -> Optional[float]:
+    """A per-layer reader's value: device ms a step of scope class
+    ``cls`` of ``classes`` in a traced run (``rec.trace["scopes"]``), or
+    None where the run is not traced or its program carries none of the
+    class's scopes (a program that names no scope puts all its time in
+    ``other``)."""
+    if rec.trace is None or "scopes" not in rec.trace:
+        return None
+    sc = rec.trace["scopes"]
+    own = {s for c, ss in classes if c == cls for s in ss}
+    if not own.intersection(sc["scopes_seen"]):
+        return None
+    return sc["ms"][cls]
 
 
 def recording(prog) -> List[dict]:

@@ -182,6 +182,15 @@ def test_run_traced_reads_the_window_in_the_buckets_of_its_last_steps(
                  "seconds": r["seconds"]}
 
 
+def recorded():
+    rec = json.loads(gzip.decompress(
+        (HERE / "testdata" / "trace_gpt2_1chip.json.gz").read_bytes()))
+    names = json.loads(gzip.decompress(
+        (HERE / "testdata" / "opnames_gpt2_1chip.json.gz").read_bytes()))
+    lo, hi = rec.pop("window")
+    return rec, (lo, hi), {names["bucket"]: names["op_names"]}
+
+
 def test_recorded_chip_trace_by_scope():
     """Two steps of the one-chip moe-gpt2 cell in rate bucket 2 (TPU v5e),
     with the op_name of each of its instructions: the program's bucket-2
@@ -232,3 +241,82 @@ def test_of_run_reads_the_maps_of_the_compiled_steps():
     assert r["ms"]["expert_ffn"] == pytest.approx(10e-6)
     assert r["found_share"] == 100.0
     assert 0 < r["seconds"] <= time.perf_counter() - t0
+
+
+def traced_record(scopes_result):
+    import harness
+    return harness.RunRecord(steps=[], window_s=1.0, chips=1,
+                             device_kind="TPU v5 lite", flops_per_token=1.0,
+                             trace={"scopes": scopes_result})
+
+
+@pytest.mark.parametrize("cls", [c for c, _ in scopes.CLASSES])
+def test_each_scope_reader_reads_its_class_of_the_recorded_trace(cls):
+    """``metrics/<class>_ms.py`` gives the class that ``attribute`` gives
+    on the recorded chip trace; nothing where the program names no scope
+    of the class, or where the run is not traced."""
+    import harness
+    rec, window, maps = recorded()
+    b = next(iter(maps))
+    r = scopes.attribute(rec, window, maps, [b, b])
+    reader = harness.load_module(HERE / "metrics" / f"{cls}_ms.py")
+    assert reader.read(traced_record(r)) == r["ms"][cls] > 0
+    plain = {b: {n: "jit(step)/dot_general" for n in maps[b]}}
+    r0 = scopes.attribute(rec, window, plain, [b, b])
+    assert reader.read(traced_record(r0)) is None
+    untraced = traced_record(r)
+    untraced.trace = None
+    assert reader.read(untraced) is None
+
+
+def test_the_six_readers_and_other_add_up_to_the_leaf_time():
+    import harness
+    rec, window, maps = recorded()
+    b = next(iter(maps))
+    r = scopes.attribute(rec, window, maps, [b, b])
+    got = [harness.load_module(HERE / "metrics" / f"{c}_ms.py").read(
+        traced_record(r)) for c, _ in scopes.CLASSES]
+    assert len(got) == 6
+    assert sum(got) + r["ms"]["other"] == pytest.approx(r["leaf_ms"],
+                                                        rel=1e-9)
+    spec = json.loads((HERE.parents[1] / "BENCHMARK.json").read_text())
+    per_layer = {m["name"]: m for m in spec["per_layer"]}
+    for c, _ in scopes.CLASSES:
+        m = per_layer[f"{c}_ms"]
+        assert (m["unit"], m["better"], m["source"], m["moves"]) == (
+            "ms", "lower", "device_trace", "tokens_per_s")
+        assert "workloads" not in m
+
+
+def test_a_layouts_classes_are_matched_first():
+    """An architecture's ``SCOPE_CLASSES`` come before ``CLASSES``: a
+    scope they name takes their class, the rest keep theirs."""
+    rec, maps = hand_trace()
+    base = scopes.attribute(rec, (0, 100_000), maps, [0, 1])
+    extra = (("head_out", ("lm_head",)),)
+    r = scopes.attribute(rec, (0, 100_000), maps, [0, 1],
+                         extra + scopes.CLASSES)
+    assert set(r["ms"]) == set(scopes.NAMES) | {"head_out"}
+    assert r["ms"]["head_out"] == pytest.approx(base["ms"]["lm_head"])
+    assert r["ms"]["lm_head"] == 0.0
+    for c in ("expert_ffn", "optimizer", "other"):
+        assert r["ms"][c] == pytest.approx(base["ms"][c])
+    assert r["scopes_seen"] == base["scopes_seen"]
+    assert scopes.class_ms(traced_record(r), "head_out", extra) == \
+        pytest.approx(base["ms"]["lm_head"])
+
+
+def test_a_traced_run_prints_its_scopes_before_the_result(capsys):
+    import run
+    out = {"correct": True, "attempted": 1, "failed": 0,
+           "metrics": {}, "device": {}, "checks": {},
+           "_info": {"setup_s": 1.0, "compiles_in_window": 0, "steps": 1,
+                     "window_s": 1.0, "buckets": [0], "max_drop": 0.0,
+                     "flops_per_token": 2.0, "program_losses": {},
+                     "reference_losses": {}, "timeline": [], "memory": {},
+                     "scopes": {"ms": {"attention": 3.0}}}}
+    run.report(out)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert "flops_per_token=2.0" in lines[0]
+    assert lines[-2] == 'scopes {"ms": {"attention": 3.0}}'
+    assert json.loads(lines[-1])["correct"] is True

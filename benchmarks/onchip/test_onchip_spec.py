@@ -47,7 +47,8 @@ def test_config_file_is_the_program_config_it_runs(name):
     assert cfg.num_layers == conf["num_layers"]
     assert cfg.moe.d_ff == conf["expert_d_ff"]
     from repro.models.model import build_model
-    weights.check_layout(conf, build_model(cfg).init_struct())
+    weights.check_layout(harness.layout_module(conf), conf,
+                         build_model(cfg).init_struct())
 
 
 def test_a_size_changed_without_listing_it_is_refused():
@@ -94,7 +95,8 @@ def test_flops_per_token_match_a_hand_count(name, dims):
     from repro.models.model import build_model
     struct = build_model(harness.program_config(conf)).init_struct()
     lens = [544, 608, 672, 736, 800, 864, 928, 992]
-    got = flops.train_flops_per_token(struct, conf, lens)
+    got = flops.train_flops_per_token(harness.layout_module(conf), struct,
+                                      conf, lens)
     assert got == pytest.approx(3 * hand_forward(*dims, lens), rel=1e-12)
     if name == "moe-gpt2-l4":
         assert 6.3e8 < got < 6.6e8
